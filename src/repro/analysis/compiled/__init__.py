@@ -22,7 +22,7 @@ from repro.analysis.compiled.hlo_lint import (check_donation,  # noqa: F401
 from repro.analysis.compiled.jaxpr_lint import (  # noqa: F401
     check_dtype_upcast, f32_dot_share)
 from repro.analysis.compiled.pallas_lint import (  # noqa: F401
-    audit_kernel, default_kernel_cases)
+    audit_kernel, check_tpu_tiling, default_kernel_cases)
 from repro.analysis.compiled.recompile import (  # noqa: F401
     check_serving_recompile, prefill_shape_census)
 from repro.analysis.compiled.sharding_lint import (  # noqa: F401

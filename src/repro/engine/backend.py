@@ -516,7 +516,11 @@ class SimBackend:
 
 
 class JaxBackend:
-    """Operators run real reduced-model forward passes from the pool.
+    """Operators run real model forward passes from the pool.
+
+    ``reduced=True`` (default) serves each architecture's small smoke
+    config; ``reduced=False`` serves the published config at full width.
+    Params are initialized from ``seed`` on JAX's default device.
 
     ``submit`` batches generation: requests are grouped by model and run
     through the fixed-slot continuous batcher (``serving/scheduler.py``),
@@ -548,7 +552,8 @@ class JaxBackend:
     def __init__(self, seed: int = 0, max_new_tokens: int = 8,
                  decode_slots: Optional[int] = None,
                  clock: Optional[Any] = None,
-                 strict_compile: bool = False):
+                 strict_compile: bool = False,
+                 reduced: bool = True):
         import time
 
         import jax
@@ -559,6 +564,7 @@ class JaxBackend:
         self._jax = jax
         self.seed = seed
         self.max_new_tokens = max_new_tokens
+        self.reduced = reduced
         # compile-path static-analysis gate (repro.analysis.compiled):
         # every model is audited once at load. False (default) runs the
         # fast jaxpr tier and surfaces findings as warnings; True also
@@ -580,7 +586,8 @@ class JaxBackend:
         self.cards = catalog()
 
     def fingerprint(self) -> Tuple[Any, ...]:
-        return ("jax", self.seed, self.max_new_tokens, self.DECODE_SLOTS)
+        return ("jax", self.seed, self.max_new_tokens, self.DECODE_SLOTS,
+                self.reduced)
 
     def close(self) -> None:
         """Backend lifecycle hook (``backend_close``): drop the model
@@ -592,7 +599,7 @@ class JaxBackend:
     def _model(self, name: str):
         if name not in self._params:
             self._audit_compile(name)
-            cfg = self._get_config(name, reduced=True)
+            cfg = self._get_config(name, reduced=self.reduced)
             params = self._api.init_params(
                 self._jax.random.PRNGKey(self.seed), cfg)
             self._params[name] = (cfg, params)
@@ -600,7 +607,7 @@ class JaxBackend:
 
     # process-wide audit memo: the lint is a pure function of the arch's
     # (frozen) config, so one report serves every backend instance
-    _audit_cache: Dict[Tuple[str, bool], Any] = {}
+    _audit_cache: Dict[Tuple[str, bool, bool], Any] = {}
 
     def _audit_compile(self, name: str) -> None:
         """Construction-time compile-path lint gate: warn by default,
@@ -610,10 +617,11 @@ class JaxBackend:
         import warnings
 
         from repro.analysis.compiled import audit_model
-        key = (name, self.strict_compile)
+        key = (name, self.strict_compile, self.reduced)
         report = self._audit_cache.get(key)
         if report is None:
-            report = audit_model(name, compile=self.strict_compile)
+            report = audit_model(name, compile=self.strict_compile,
+                                 reduced=self.reduced)
             self._audit_cache[key] = report
         if self.strict_compile:
             report.raise_for_errors()

@@ -115,7 +115,7 @@ def flash_attention_gqa(
     softcap: float = 0.0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool,
     scale: float = 0.0,  # 0 -> head_dim**-0.5 (pass explicitly when padded)
 ) -> jax.Array:
     b, kh, g, s, hd = q.shape

@@ -12,6 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import (
     DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention_gqa)
 
@@ -40,7 +41,7 @@ def flash_attention(
     softcap: float = 0.0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, s, h, hd = q.shape
     kh = k.shape[2]
@@ -81,7 +82,7 @@ def flash_attention(
         softcap=softcap,
         block_q=bq,
         block_k=bk,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         scale=hd ** -0.5,
     )
     out = out[:, :, :, :s, :hd]

@@ -1,19 +1,31 @@
 """Pallas TPU flash-decode kernel: one query position vs a long KV cache.
 
-This is the serving hot spot — decode_32k/long_500k cells stream the KV
-cache per step, and §Perf shows the XLA path additionally materializes
-expanded/transposed copies. The kernel:
+This is the serving hot spot: every decode step streams the KV cache. The
+kernel:
 
 - never expands GQA: the grid iterates (batch, kv-head, kv-blocks) and the
   per-kv-head query group (G = H/K rows) rides in VMEM as a (G, Hd) tile;
+- reads the cache in a (B, K, S, Hd) layout, so a K/V block is a
+  (block_s, Hd) tile whose last two dims meet the TPU's (8, 128) tiling
+  rule;
 - runs online softmax over kv blocks (innermost sequential grid dim) with
-  (G,1)/(G,Hd) running max/denominator/accumulator in VMEM scratch — one
-  pass over the cache, no (H, S) score tensor in HBM;
-- masks by the *dynamic* cache length: ``valid_len`` arrives as a (1,)
-  array indexed per block (SMEM scalar prefetch on real hardware).
+  (G,1)/(G,Hd) running max/denominator/accumulator in VMEM scratch: the
+  kernel itself reads its K/V operands once, and no (H, S) score tensor
+  reaches HBM;
+- masks by the *dynamic* cache length: ``valid_len`` is a (1,) int32
+  scalar-prefetch operand, so it sits in SMEM before the grid starts and
+  blocks wholly past it skip their compute.
 
 Supports GQA/MQA, softcap. Ring-buffer local caches use the jnp path (the
 ring index arithmetic is cheap at window size).
+
+The model keeps its cache as (B, S, K, Hd), so the wrapper (``ops.py``)
+transposes all of K and V to (B, K, S, Hd) on every decode step, and
+pads Hd up to a multiple of 128 (64 -> 128 for llama): a full, padded
+copy of the cache is written to HBM and read back before the kernel
+starts. Counting HBM traffic, one step costs several cache-sized passes,
+not one. Until the cache owner stores (B, K, S, Hd) itself, a comparison
+of this path against the jnp one must count that copy.
 """
 
 from __future__ import annotations
@@ -31,10 +43,10 @@ DEFAULT_BLOCK_S = 512
 
 
 def _decode_kernel(
-    len_ref,   # (1,) int32 — number of valid cache entries
+    len_ref,   # SMEM (1,) int32: number of valid cache entries
     q_ref,     # (1, 1, G, Hd)
-    k_ref,     # (1, bs, 1, Hd)
-    v_ref,     # (1, bs, 1, Hd)
+    k_ref,     # (1, 1, bs, Hd)
+    v_ref,     # (1, 1, bs, Hd)
     o_ref,     # (1, 1, G, Hd)
     m_ref, l_ref, acc_ref,  # scratch: (G,1), (G,1), (G,Hd) fp32
     *,
@@ -56,9 +68,9 @@ def _decode_kernel(
 
     @pl.when(s_start < valid_len)  # skip fully-invalid cache blocks
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, Hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (bs, Hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)   # (G, Hd)
+        k = k_ref[0, 0].astype(jnp.float32)   # (bs, Hd)
+        v = v_ref[0, 0].astype(jnp.float32)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -86,37 +98,41 @@ def _decode_kernel(
 
 def flash_decode_gqa(
     q: jax.Array,          # (B, K, G, Hd)
-    k: jax.Array,          # (B, S, K, Hd)
+    k: jax.Array,          # (B, K, S, Hd)
     v: jax.Array,
     valid_len: jax.Array,  # (1,) int32
     *,
+    interpret: bool,
     softcap: float = 0.0,
     block_s: int = DEFAULT_BLOCK_S,
-    interpret: bool = True,
     scale: float = 0.0,
 ) -> jax.Array:
     b, kh, g, hd = q.shape
-    s = k.shape[1]
+    s = k.shape[2]
     block_s = min(block_s, s)
     nsb = pl.cdiv(s, block_s)
     scale = scale or hd ** -0.5
     kernel = functools.partial(_decode_kernel, scale=scale, softcap=softcap,
                                block_s=block_s)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, kh, nsb),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, i: (0,)),
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda b, h, i: (b, i, h, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda b, h, i: (b, i, h, 0)),
+            pl.BlockSpec((1, 1, g, hd), lambda b, h, i, n: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_s, hd), lambda b, h, i, n: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_s, hd), lambda b, h, i, n: (b, h, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, i: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, hd),
+                               lambda b, h, i, n: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
         interpret=interpret,
     )(valid_len, q, k, v)

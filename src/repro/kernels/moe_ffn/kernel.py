@@ -61,7 +61,7 @@ def moe_expert_ffn(
     *,
     block_c: int = 128,
     block_f: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     g, e, c, d = x.shape
     f = w_gate.shape[-1]

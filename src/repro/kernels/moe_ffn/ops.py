@@ -3,10 +3,12 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.moe_ffn.kernel import moe_expert_ffn
 
 
@@ -19,7 +21,7 @@ def expert_ffn(
     *,
     block_c: int = 128,
     block_f: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     g, e, c, d = x.shape
     f = w_gate.shape[-1]
@@ -42,5 +44,5 @@ def expert_ffn(
         w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, f_pad)))
         w_down = jnp.pad(w_down, ((0, 0), (0, f_pad), (0, 0)))
     out = moe_expert_ffn(x, w_gate, w_up, w_down,
-                         block_c=bc, block_f=bf, interpret=interpret)
+                         block_c=bc, block_f=bf, interpret=resolve_interpret(interpret))
     return out[:, :, :c, :]

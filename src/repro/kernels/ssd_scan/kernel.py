@@ -97,7 +97,7 @@ def ssd_scan(
     h0: jax.Array,   # (B, H, P, N)
     *,
     chunk: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
     b, h, s, p = x.shape
     g, n = Bm.shape[1], Bm.shape[3]

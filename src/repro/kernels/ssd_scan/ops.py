@@ -11,6 +11,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan
 
 
@@ -24,7 +25,7 @@ def ssd(
     D: jax.Array,    # (H,)
     chunk: int,
     initial_state: Optional[jax.Array] = None,  # (B, H, P, N)
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     b, s, h, p = x.shape
     n = Bm.shape[-1]
@@ -51,6 +52,6 @@ def ssd(
         D,
         h0.astype(jnp.float32),
         chunk=chunk,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return y.transpose(0, 2, 1, 3), hf

@@ -37,14 +37,25 @@ Usage:
       --policy adaptive --slo-s 2 --swap-after 4 --requests 8
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
       --requests 8 --reopt --reopt-mode propose
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
+      --full-width --requests 8    # published config (on a TPU)
+
+``--full-width`` serves the architecture's published config instead of
+its reduced smoke config. The command exits non-zero when any request
+failed. ``main()`` keeps JAX's persistent compile cache where
+``JAX_COMPILATION_CACHE_DIR`` says, or else in ``.jax_cache/`` at the
+root of the checkout.
 """
 
 from __future__ import annotations
 
 import argparse
+import os
 import random
+import sys
 import time
 import warnings
+from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.engine.workloads import WORKLOADS
@@ -54,6 +65,30 @@ from repro.serving.multi_server import MultiPipelineServer, TenantSpec
 from repro.serving.pipeline_server import (MonotonicClock, PipelineServer,
                                            ServeTicket)
 from repro.serving.reopt import ReoptLoop
+
+
+#: the fixed compile-cache directory used when JAX_COMPILATION_CACHE_DIR
+#: is unset: the root of the checkout, so every run from it hits the same
+#: cache (a temporary or per-process path would never hit)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache for an entry point (never
+    at import). With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads it
+    itself and nothing is set here; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
+def failed_tickets(tickets: List[ServeTicket]) -> List[ServeTicket]:
+    """Tickets that resolved with an error instead of output docs."""
+    return [tk for tk in tickets if tk.error is not None]
 
 
 def pipeline_for(workload, arch: str) -> Dict[str, Any]:
@@ -128,13 +163,14 @@ def _reopt_loop(server, workload, *, mode: str, budget: int,
 
 
 def _drive(server, submits, *, rps: float, seed: int,
-           after_drain: Optional[Callable[[], None]] = None
+           after_drain: Optional[Callable[[], None]] = None,
+           close_backend: bool = True
            ) -> Tuple[List[ServeTicket], Dict[str, Any]]:
     """Shared open-loop drive: start the server, pace the ``submits``
     callables (each admits one request) at Poisson ``rps`` (0 = all at
     once), drain, run ``after_drain`` (the re-optimization hook — the
-    backend is still open), shut down (closing the backend), and
-    report against wall time."""
+    backend is still open), shut down (closing the backend unless
+    ``close_backend=False``), and report against wall time."""
     rng = random.Random(seed)
     t0 = time.monotonic()
     server.start()
@@ -148,7 +184,7 @@ def _drive(server, submits, *, rps: float, seed: int,
         if after_drain is not None:
             after_drain()
     finally:
-        server.shutdown(close_backend=True)
+        server.shutdown(close_backend=close_backend)
     return tickets, server.report(elapsed_s=time.monotonic() - t0)
 
 
@@ -159,7 +195,8 @@ def serve_demo(arch: str, *, requests: int = 8, slots: int = 4,
                policy: str = "static", slo_s: Optional[float] = None,
                max_queue: int = 16, swap_after: int = 0,
                reopt: bool = False, reopt_mode: str = "auto",
-               reopt_budget: int = 8, slo_ms: Optional[float] = None
+               reopt_budget: int = 8, slo_ms: Optional[float] = None,
+               reduced: bool = True, backend: Optional[Any] = None
                ) -> Tuple[List[ServeTicket], Dict[str, Any]]:
     """End-to-end online serving demo on real JAX decoding.
 
@@ -180,6 +217,14 @@ def serve_demo(arch: str, *, requests: int = 8, slots: int = 4,
     documents and runs one re-optimization pass once the trace drains
     (the live backend is still open), auto-promoting or proposing per
     ``reopt_mode``.
+
+    ``reduced=False`` serves the published config at full width. A
+    caller that passes its own ``backend`` keeps it open after the
+    drain, e.g. to read the served params; otherwise the demo builds one
+    and closes it. A passed backend must have been built with this
+    call's ``seed``, ``max_new``, ``slots`` and ``reduced``, or the demo
+    raises ``ValueError`` rather than serve another configuration than
+    it reports.
     """
     from repro.engine.backend import JaxBackend  # jax import is heavy
 
@@ -187,10 +232,20 @@ def serve_demo(arch: str, *, requests: int = 8, slots: int = 4,
     w = WORKLOADS[workload]()
     plan = pipeline_for(w, arch)
     # one clock for host and batcher: scheduler timestamps join the
-    # server's timeline
+    # server's timeline (MonotonicClock instances share one timeline)
     clock = MonotonicClock()
-    backend = JaxBackend(seed=seed, max_new_tokens=max_new,
-                         decode_slots=slots, clock=clock)
+    own_backend = backend is None
+    if own_backend:
+        backend = JaxBackend(seed=seed, max_new_tokens=max_new,
+                             decode_slots=slots, clock=clock,
+                             reduced=reduced)
+    else:
+        want = {"seed": seed, "max_new_tokens": max_new,
+                "DECODE_SLOTS": slots, "reduced": reduced}
+        got = {k: getattr(backend, k, None) for k in want}
+        if got != want:
+            raise ValueError(f"serve_demo: the backend was built with "
+                             f"{got}, not this demo's {want}")
     max_batch = max_batch or max(1, 2 * slots)
     server = PipelineServer(plan, backend, max_inflight=4 * max_batch,
                             max_batch=max_batch, batch_window_s=0.01,
@@ -216,9 +271,14 @@ def serve_demo(arch: str, *, requests: int = 8, slots: int = 4,
         server, [lambda i=i, d=doc: submit(i, d)
                  for i, doc in enumerate(docs)],
         rps=rps, seed=seed,
-        after_drain=reoptimize if loop is not None else None)
+        after_drain=reoptimize if loop is not None else None,
+        close_backend=own_backend)
     if verbose:
         for tk in tickets:
+            if tk.error is not None:
+                print(f"  req {tk.rid}: FAILED "
+                      f"{type(tk.error).__name__}: {tk.error}")
+                continue
             n_out = len(tk.docs) if tk.docs is not None else 0
             st = tk.stats
             print(f"  req {tk.rid}: {n_out} output docs in "
@@ -280,14 +340,15 @@ def serve_multi_demo(arch: str, tenants: str, *, requests: int = 8,
                      slo_s: Optional[float] = None, max_queue: int = 16,
                      swap_after: int = 0, reopt: bool = False,
                      reopt_mode: str = "auto", reopt_budget: int = 8,
-                     slo_ms: Optional[float] = None
+                     slo_ms: Optional[float] = None, reduced: bool = True
                      ) -> Tuple[List[ServeTicket], Dict[str, Any]]:
     """Multi-tenant online serving on real JAX decoding: the roster's
     plans share one backend; requests round-robin across tenants at the
     submission side and coalesce across tenants inside the host.
     ``swap_after=N`` hot-swaps the *first* tenant's plan after the Nth
     submission; ``reopt=True`` re-optimizes every tenant from its own
-    reservoir once the trace drains."""
+    reservoir once the trace drains; ``reduced=False`` serves the
+    published config."""
     from repro.engine.backend import JaxBackend  # jax import is heavy
 
     slo_s = _resolve_slo(slo_s, slo_ms)
@@ -298,7 +359,7 @@ def serve_multi_demo(arch: str, tenants: str, *, requests: int = 8,
     samples = {name: w.sample for name, w in workloads.items()}
     clock = MonotonicClock()
     backend = JaxBackend(seed=seed, max_new_tokens=max_new,
-                         decode_slots=slots, clock=clock)
+                         decode_slots=slots, clock=clock, reduced=reduced)
     max_batch = max_batch or max(1, 2 * slots)
     server = MultiPipelineServer(specs, backend,
                                  max_inflight=4 * max_batch,
@@ -342,6 +403,9 @@ def serve_multi_demo(arch: str, tenants: str, *, requests: int = 8,
                   f"{rep['completed']} served, "
                   f"{rep['dispatched']['requests']} dispatched reqs, "
                   f"p50 {rep['latency_s']['p50']:.2f}s")
+        for tk in failed_tickets(tickets):
+            print(f"  req {tk.rid} ({tk.tenant}): FAILED "
+                  f"{type(tk.error).__name__}: {tk.error}")
     return tickets, report
 
 
@@ -390,26 +454,37 @@ def main():
                          "swap_plan, or emit a PromotionProposal")
     ap.add_argument("--reopt-budget", type=int, default=8,
                     help="evaluation budget of the background search")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the architecture's published config "
+                         "instead of its reduced smoke config")
     args = ap.parse_args()
+    # keep libtpu's logs out of its fixed default directory under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    enable_compile_cache()
     if args.tenants:
-        serve_multi_demo(args.arch, args.tenants, requests=args.requests,
-                         slots=args.slots, rps=args.rps,
-                         max_new=args.max_new, max_batch=args.max_batch,
-                         workers=args.workers, seed=args.seed,
-                         policy=args.policy, slo_s=args.slo_s,
-                         slo_ms=args.slo_ms, max_queue=args.max_queue,
-                         swap_after=args.swap_after, reopt=args.reopt,
-                         reopt_mode=args.reopt_mode,
-                         reopt_budget=args.reopt_budget)
-        return
-    serve_demo(args.arch, requests=args.requests, slots=args.slots,
-               rps=args.rps, max_new=args.max_new, workload=args.workload,
-               max_batch=args.max_batch, workers=args.workers,
-               seed=args.seed, policy=args.policy, slo_s=args.slo_s,
-               slo_ms=args.slo_ms, max_queue=args.max_queue,
-               swap_after=args.swap_after, reopt=args.reopt,
-               reopt_mode=args.reopt_mode,
-               reopt_budget=args.reopt_budget)
+        tickets, _ = serve_multi_demo(
+            args.arch, args.tenants, requests=args.requests,
+            slots=args.slots, rps=args.rps, max_new=args.max_new,
+            max_batch=args.max_batch, workers=args.workers, seed=args.seed,
+            policy=args.policy, slo_s=args.slo_s, slo_ms=args.slo_ms,
+            max_queue=args.max_queue, swap_after=args.swap_after,
+            reopt=args.reopt, reopt_mode=args.reopt_mode,
+            reopt_budget=args.reopt_budget, reduced=not args.full_width)
+    else:
+        tickets, _ = serve_demo(
+            args.arch, requests=args.requests, slots=args.slots,
+            rps=args.rps, max_new=args.max_new, workload=args.workload,
+            max_batch=args.max_batch, workers=args.workers,
+            seed=args.seed, policy=args.policy, slo_s=args.slo_s,
+            slo_ms=args.slo_ms, max_queue=args.max_queue,
+            swap_after=args.swap_after, reopt=args.reopt,
+            reopt_mode=args.reopt_mode, reopt_budget=args.reopt_budget,
+            reduced=not args.full_width)
+    failed = failed_tickets(tickets)
+    if failed:
+        print(f"[serve] {len(failed)}/{len(tickets)} requests failed",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -226,7 +226,6 @@ def attn_prefill(
             causal=True,
             window=int(window) if isinstance(window, int) else None,
             softcap=cfg.attn_softcap,
-            interpret=cfg.pallas_interpret,
         )
     else:
         q_pos2 = positions if positions.ndim == 2 else positions[None]
@@ -340,7 +339,6 @@ def attn_decode(
         out = fd_ops.flash_decode(
             q, cache_k, cache_v, cache_len + 1,
             softcap=cfg.attn_softcap,
-            interpret=cfg.pallas_interpret,
         ).reshape(b, 1, cfg.num_heads, -1)
         out = out.reshape(b, 1, -1)
         return (jnp.einsum("...e,ed->...d", out, params["wo"]),

@@ -79,7 +79,6 @@ class ModelConfig:
     kv_cache_dtype: str = ""       # "" = dtype; "int8" = quantized KV cache
     remat: str = "none"            # "none" | "full" — activation checkpointing
     use_pallas: bool = False       # route hot ops through Pallas kernels
-    pallas_interpret: bool = True  # interpret-mode on CPU; False on real TPU
     max_seq_len: int = 1 << 19
 
     # ------------------------------------------------------------------ api --

@@ -92,7 +92,6 @@ def _expert_ffn(params, xin: jax.Array, cfg: ModelConfig) -> jax.Array:
         out = moe_ops.expert_ffn(
             xin.reshape(g * e, c, d).reshape(g, e, c, d),  # no-op, kept for clarity
             params["w_gate"], params["w_up"], params["w_down"],
-            interpret=cfg.pallas_interpret,
         )
         return out
     gate = jnp.einsum("gecd,edf->gecf", xin, params["w_gate"])

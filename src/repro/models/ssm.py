@@ -206,8 +206,7 @@ def mamba_prefill(
     if cfg.use_pallas:
         from repro.kernels.ssd_scan import ops as ssd_ops
         y, fstate = ssd_ops.ssd(xs, dtv, A, bm, cm, params["D"], chunk,
-                                initial_state=init_state,
-                                interpret=cfg.pallas_interpret)
+                                initial_state=init_state)
     else:
         y, fstate = ssd_chunked(xs, dtv, A, bm, cm, params["D"], chunk,
                                 initial_state=init_state)

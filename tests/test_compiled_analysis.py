@@ -17,8 +17,8 @@ from repro.analysis.compiled import (  # noqa: E402
     PALLAS_BLOCK_SHAPE, PALLAS_VMEM, RECOMPILE_RISK, SHARDING_INCONSISTENCY,
     CompiledAnalysisError, CompiledReport, audit_kernel, audit_kernels,
     audit_model, check_donation, check_dtype_upcast, check_serving_recompile,
-    check_transfers, merge_reports, parse_declared_donors, parse_io_aliases,
-    validate_spec_tree)
+    check_tpu_tiling, check_transfers, merge_reports, parse_declared_donors,
+    parse_io_aliases, validate_spec_tree)
 from repro.configs import get_config  # noqa: E402
 
 # -- transfer lint (synthetic HLO) -----------------------------------------
@@ -213,6 +213,18 @@ def test_pallas_vmem_budget_override():
                          b=1, s=512, h=4, kh=2, hd=64, block_s=128,
                          vmem_bytes=64 * 1024)
     assert [d.code for d in diags] == [PALLAS_VMEM]
+
+
+def test_pallas_block_shape_tpu_tiling_rule():
+    # flash_decode's old K/V tile on a (B, S, K, Hd) cache at llama3.2-1b
+    # decode widths: the chip's compiler refused its (1, 128) last dims
+    diags = check_tpu_tiling("t", "flash_decode", "k",
+                             (1, 112, 1, 128), (4, 112, 8, 128))
+    assert [d.code for d in diags] == [PALLAS_BLOCK_SHAPE]
+    assert "tiling" in diags[0].message
+    # the (B, K, S, Hd) layout the kernel reads now passes
+    assert check_tpu_tiling("t", "flash_decode", "k",
+                            (1, 1, 112, 128), (4, 8, 112, 128)) == []
 
 
 def test_audit_kernel_unknown_name_raises():

@@ -130,6 +130,42 @@ def test_continuous_batcher_drains():
     assert report["out_tokens"] > 0 and report["batches"] >= 1
 
 
+def test_serve_cli_exits_nonzero_when_a_request_fails(monkeypatch):
+    # a backend whose every submit raises: PipelineServer resolves each
+    # ticket with the error, and the CLI must not exit 0 over them
+    from repro.engine import backend as be
+    from repro.launch import serve
+
+    class DeviceLost(be.SimBackend):
+        def __init__(self, seed=0, **_):
+            super().__init__(seed=seed)
+
+        def submit(self, requests):
+            raise RuntimeError("device lost")
+
+    monkeypatch.setattr(be, "JaxBackend", DeviceLost)
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    monkeypatch.setattr("sys.argv", ["serve", "--requests", "2"])
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code == 1
+
+
+@pytest.mark.parametrize("mismatch", [{"max_new_tokens": 4},
+                                      {"decode_slots": 2}, {"seed": 1},
+                                      {"reduced": False}])
+def test_serve_demo_refuses_a_backend_built_otherwise(mismatch):
+    # a passed backend must serve the configuration the demo reports
+    from repro.engine.backend import JaxBackend
+    from repro.launch.serve import serve_demo
+    kw = dict({"seed": 0, "max_new_tokens": 8, "decode_slots": 4}, **mismatch)
+    backend = JaxBackend(**kw)
+    with pytest.raises(ValueError, match="backend was built with"):
+        serve_demo("llama3.2-1b", requests=1, slots=4, max_new=8, seed=0,
+                   backend=backend, verbose=False)
+
+
 def test_cache_bytes_matches_measured():
     from repro.serving.kv_cache import cache_bytes, make_cache, \
         measured_cache_bytes

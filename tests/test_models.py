@@ -90,7 +90,7 @@ def test_decode_matches_forward(arch, rng):
 def test_pallas_routing_matches_jnp(arch, rng):
     cfg0 = get_config(arch, reduced=True).replace(dtype="float32",
                                                   param_dtype="float32")
-    cfg1 = cfg0.replace(use_pallas=True, pallas_interpret=True)
+    cfg1 = cfg0.replace(use_pallas=True)
     params = api.init_params(rng, cfg0)
     toks = jax.random.randint(rng, (2, 32), 0, cfg0.vocab_size)
     l0, _ = api.forward(params, cfg0, tokens=toks)
@@ -188,7 +188,7 @@ def test_flash_decode_routing_matches_forward(rng):
     """cfg.use_pallas decode path (flash-decode kernel) == full forward."""
     cfg0 = get_config("llama3.2-1b", reduced=True).replace(
         dtype="float32", param_dtype="float32")
-    cfg1 = cfg0.replace(use_pallas=True, pallas_interpret=True)
+    cfg1 = cfg0.replace(use_pallas=True)
     params = api.init_params(rng, cfg0)
     toks = jax.random.randint(rng, (2, 20), 0, cfg0.vocab_size)
     full, _ = api.forward(params, cfg0, tokens=toks)
