@@ -551,11 +551,8 @@ class JaxBackend:
 
     def __init__(self, seed: int = 0, max_new_tokens: int = 8,
                  decode_slots: Optional[int] = None,
-                 clock: Optional[Any] = None,
                  strict_compile: bool = False,
                  reduced: bool = True):
-        import time
-
         import jax
         from repro.configs import get_config
         from repro.models import api
@@ -572,15 +569,6 @@ class JaxBackend:
         self.strict_compile = strict_compile
         if decode_slots is not None:
             self.DECODE_SLOTS = max(1, int(decode_slots))
-        # threaded into each ContinuousBatcher so request timestamps can
-        # participate in a host's (possibly virtual) timeline; accepts a
-        # bare callable or a serving-layer clock object (.now())
-        if clock is None:
-            self.clock = time.time
-        elif callable(getattr(clock, "now", None)):
-            self.clock = clock.now
-        else:
-            self.clock = clock
         self._params = {}
         self._batchers: Dict[str, Any] = {}
         self.cards = catalog()
@@ -631,23 +619,28 @@ class JaxBackend:
     # -- batched dispatch (Backend protocol v2) -------------------------------
 
     def submit(self, requests: List[OpRequest]) -> List[OpResult]:
-        results: List[Optional[OpResult]] = [None] * len(requests)
-        by_model: Dict[str, List[int]] = {}
-        for i, req in enumerate(requests):
-            if req.kind == "resolve":
-                results[i] = OpResult(value=list(req.docs), usage=Usage())
-            elif req.kind == "equijoin":
-                value, usage = default_equijoin(req.op, req.doc)
-                results[i] = OpResult(value=value, usage=usage)
-            else:
-                by_model.setdefault(req.op["model"], []).append(i)
-        for model, idxs in by_model.items():
-            prompts = [self._prompt_for(requests[i]) for i in idxs]
-            for i, (toks, usage) in zip(idxs,
-                                        self._generate_batch(model, prompts)):
-                results[i] = OpResult(
-                    value=self._value_for(requests[i], toks), usage=usage)
-        return results
+        # a profiler span on the trace's clock (see serving/scheduler.py)
+        with self._jax.profiler.TraceAnnotation("backend.submit",
+                                                requests=len(requests)):
+            results: List[Optional[OpResult]] = [None] * len(requests)
+            by_model: Dict[str, List[int]] = {}
+            for i, req in enumerate(requests):
+                if req.kind == "resolve":
+                    results[i] = OpResult(value=list(req.docs),
+                                          usage=Usage())
+                elif req.kind == "equijoin":
+                    value, usage = default_equijoin(req.op, req.doc)
+                    results[i] = OpResult(value=value, usage=usage)
+                else:
+                    by_model.setdefault(req.op["model"], []).append(i)
+            for model, idxs in by_model.items():
+                prompts = [self._prompt_for(requests[i]) for i in idxs]
+                for i, (toks, usage) in zip(
+                        idxs, self._generate_batch(model, prompts)):
+                    results[i] = OpResult(
+                        value=self._value_for(requests[i], toks),
+                        usage=usage)
+            return results
 
     def _prompt_for(self, req: OpRequest) -> str:
         op = req.op
@@ -692,8 +685,7 @@ class JaxBackend:
             b = ContinuousBatcher(
                 params, cfg, num_slots=self.DECODE_SLOTS,
                 max_len=self.MAX_PROMPT_TOKENS + self.max_new_tokens + 8,
-                eos_id=-1,  # match generate(): no early EOS stop
-                clock=self.clock)
+                eos_id=-1)  # match generate(): no early EOS stop
             self._batchers[model] = b
         return b
 

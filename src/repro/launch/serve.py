@@ -62,8 +62,7 @@ from repro.engine.workloads import WORKLOADS
 from repro.pipeline.model import as_config
 from repro.serving.control import AdaptivePolicy, ControlPolicy
 from repro.serving.multi_server import MultiPipelineServer, TenantSpec
-from repro.serving.pipeline_server import (MonotonicClock, PipelineServer,
-                                           ServeTicket)
+from repro.serving.pipeline_server import PipelineServer, ServeTicket
 from repro.serving.reopt import ReoptLoop
 
 
@@ -231,14 +230,10 @@ def serve_demo(arch: str, *, requests: int = 8, slots: int = 4,
     slo_s = _resolve_slo(slo_s, slo_ms)
     w = WORKLOADS[workload]()
     plan = pipeline_for(w, arch)
-    # one clock for host and batcher: scheduler timestamps join the
-    # server's timeline (MonotonicClock instances share one timeline)
-    clock = MonotonicClock()
     own_backend = backend is None
     if own_backend:
         backend = JaxBackend(seed=seed, max_new_tokens=max_new,
-                             decode_slots=slots, clock=clock,
-                             reduced=reduced)
+                             decode_slots=slots, reduced=reduced)
     else:
         want = {"seed": seed, "max_new_tokens": max_new,
                 "DECODE_SLOTS": slots, "reduced": reduced}
@@ -249,8 +244,7 @@ def serve_demo(arch: str, *, requests: int = 8, slots: int = 4,
     max_batch = max_batch or max(1, 2 * slots)
     server = PipelineServer(plan, backend, max_inflight=4 * max_batch,
                             max_batch=max_batch, batch_window_s=0.01,
-                            workers=workers, seed=seed, clock=clock,
-                            slo_s=slo_s,
+                            workers=workers, seed=seed, slo_s=slo_s,
                             policy=_policy_for(policy,
                                                max_queue=max_queue))
     loop = (_reopt_loop(server, w, mode=reopt_mode, budget=reopt_budget,
@@ -357,15 +351,14 @@ def serve_multi_demo(arch: str, tenants: str, *, requests: int = 8,
     workloads = {spec.name: WORKLOADS[wname]() for spec, wname in roster}
     # tenant name keys the roster; its workload's sample feeds traffic
     samples = {name: w.sample for name, w in workloads.items()}
-    clock = MonotonicClock()
     backend = JaxBackend(seed=seed, max_new_tokens=max_new,
-                         decode_slots=slots, clock=clock, reduced=reduced)
+                         decode_slots=slots, reduced=reduced)
     max_batch = max_batch or max(1, 2 * slots)
     server = MultiPipelineServer(specs, backend,
                                  max_inflight=4 * max_batch,
                                  max_batch=max_batch,
                                  batch_window_s=0.01, workers=workers,
-                                 seed=seed, clock=clock, slo_s=slo_s,
+                                 seed=seed, slo_s=slo_s,
                                  policy=_policy_for(policy,
                                                     max_queue=max_queue))
     loop = (_reopt_loop(server, workloads, mode=reopt_mode,

@@ -5,14 +5,23 @@ finished/empty slots are refilled from the queue between steps (prefill for
 the incoming request, cache splice into the slot). This is the standard
 TPU-serving shape: the decode step has a static (slots, 1) signature so it
 compiles once, and admission happens on the host between steps.
+
+The batcher marks its host work with profiler spans
+(``jax.profiler.TraceAnnotation``), which land on the trace's clock
+beside the device's planes and cost about a microsecond each when no
+profiler runs: ``batcher.tick`` (a ``StepTraceAnnotation`` numbered by
+tick), ``batcher.admit`` per request taken from the queue (``uid``,
+``prompt_len``, ``bucket``) holding ``batcher.prefill`` and
+``batcher.splice``, ``batcher.decode`` (``active``, ``slots``) holding
+``batcher.step``, and ``batcher.sync`` around each device-to-host read.
+Every stat is a host integer known when its span opens.
 """
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Deque, List, Optional
+from typing import Deque, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +30,8 @@ import numpy as np
 from repro.models import api
 from repro.models.config import ModelConfig
 from repro.serving.decode import SERVE_STEP_DONATE, make_serve_step
+
+span = jax.profiler.TraceAnnotation
 
 #: prompts right-pad to multiples of this before prefill, so the prefill
 #: jit site sees a handful of shapes instead of one per distinct prompt
@@ -47,8 +58,6 @@ class Request:
     max_new_tokens: int = 32
     generated: List[int] = field(default_factory=list)
     done: bool = False
-    submitted_at: float = 0.0
-    finished_at: float = 0.0
 
 
 class SchedulerStalled(RuntimeError):
@@ -71,23 +80,15 @@ class SchedulerStalled(RuntimeError):
 
 
 class ContinuousBatcher:
-    """Single-host scheduler over a fixed decode batch.
-
-    ``clock`` stamps ``Request.submitted_at`` / ``finished_at``; it
-    defaults to ``time.time`` but serving hosts that account latency on
-    a virtual clock inject their own callable so batcher timestamps
-    participate in the same deterministic timeline.
-    """
+    """Single-host scheduler over a fixed decode batch."""
 
     def __init__(self, params, cfg: ModelConfig, num_slots: int = 4,
-                 max_len: int = 512, eos_id: int = 2,
-                 clock: Callable[[], float] = time.time):
+                 max_len: int = 512, eos_id: int = 2):
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
         self.max_len = max_len
         self.eos_id = eos_id
-        self.clock = clock
         self.queue: Deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * num_slots
         self.cache = api.init_cache(cfg, num_slots, max_len)
@@ -95,6 +96,7 @@ class ContinuousBatcher:
         self._step = jax.jit(make_serve_step(cfg),
                              donate_argnums=SERVE_STEP_DONATE)
         self._uid = 0
+        self._ticks = 0
         self.finished: List[Request] = []
         # per-slot position bookkeeping (host side)
         self._slot_len = [0] * num_slots
@@ -102,14 +104,13 @@ class ContinuousBatcher:
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 32) -> int:
         self._uid += 1
         self.queue.append(Request(self._uid, np.asarray(prompt, np.int32),
-                                  max_new_tokens, submitted_at=self.clock()))
+                                  max_new_tokens))
         return self._uid
 
     # -- internals ---------------------------------------------------------
 
     def _retire(self, req: Request) -> None:
         req.done = True
-        req.finished_at = self.clock()
         self.finished.append(req)
 
     def _admit(self):
@@ -124,46 +125,56 @@ class ContinuousBatcher:
                 continue
             while self.queue:
                 req = self.queue.popleft()
-                # right-pad to a bucketed length: one prefill trace per
-                # bucket instead of one per distinct prompt length
-                true_len = len(req.prompt)
-                blen = bucket_len(true_len, self.max_len)
-                ids = np.zeros((1, blen), np.int32)
-                ids[0, :true_len] = req.prompt
+                if self._admit_one(req, slot):
+                    break
+
+    def _admit_one(self, req: Request, slot: int) -> bool:
+        """Prefill ``req`` and splice it into ``slot``; False when it
+        retired at prefill and the slot is still free."""
+        # right-pad to a bucketed length: one prefill trace per bucket
+        # instead of one per distinct prompt length
+        true_len = len(req.prompt)
+        blen = bucket_len(true_len, self.max_len)
+        with span("batcher.admit", uid=req.uid, prompt_len=true_len,
+                  bucket=blen):
+            ids = np.zeros((1, blen), np.int32)
+            ids[0, :true_len] = req.prompt
+            with span("batcher.prefill"):
                 logits, cache1 = api.prefill(self.params, self.cfg,
                                              self.max_len,
                                              tokens=jnp.asarray(ids))
+            with span("batcher.sync"):
                 tok = int(jnp.argmax(logits[0, true_len - 1]))
-                req.generated.append(tok)
-                if tok == self.eos_id or \
-                        len(req.generated) >= req.max_new_tokens:
-                    # done at prefill: retire without touching the batch
-                    # cache and offer the slot to the next queued request
-                    self._retire(req)
-                    continue
+            req.generated.append(tok)
+            if tok == self.eos_id or \
+                    len(req.generated) >= req.max_new_tokens:
+                # done at prefill: retire without touching the batch
+                # cache and offer the slot to the next queued request
+                self._retire(req)
+                return False
 
-                # splice single-sequence cache into the batch cache
-                def splice(batch_leaf, one_leaf, slot=slot):
-                    if batch_leaf.ndim == 0 or \
-                            one_leaf.shape == batch_leaf.shape:
-                        return batch_leaf
-                    # find the batch axis: the axis where shapes differ
-                    for ax in range(batch_leaf.ndim):
-                        if batch_leaf.shape[ax] == self.num_slots and \
-                                one_leaf.shape[ax] == 1:
-                            return jax.lax.dynamic_update_slice_in_dim(
-                                batch_leaf,
-                                one_leaf.astype(batch_leaf.dtype),
-                                slot, axis=ax)
+            # splice single-sequence cache into the batch cache
+            def splice(batch_leaf, one_leaf):
+                if batch_leaf.ndim == 0 or \
+                        one_leaf.shape == batch_leaf.shape:
                     return batch_leaf
+                # find the batch axis: the axis where shapes differ
+                for ax in range(batch_leaf.ndim):
+                    if batch_leaf.shape[ax] == self.num_slots and \
+                            one_leaf.shape[ax] == 1:
+                        return jax.lax.dynamic_update_slice_in_dim(
+                            batch_leaf, one_leaf.astype(batch_leaf.dtype),
+                            slot, axis=ax)
+                return batch_leaf
+            with span("batcher.splice"):
                 new_cache = jax.tree.map(splice, dict(self.cache),
                                          dict(cache1))
                 new_cache["len"] = self.cache["len"]  # batch len: see step
                 self.cache = new_cache
                 self.tokens = self.tokens.at[slot, 0].set(tok)
-                self.slots[slot] = req
-                self._slot_len[slot] = len(req.prompt)
-                break
+            self.slots[slot] = req
+            self._slot_len[slot] = true_len
+            return True
 
     def _uniform_len(self) -> int:
         """The batch cache tracks one length; slots prefix-pad to align.
@@ -173,24 +184,34 @@ class ContinuousBatcher:
     def step(self) -> int:
         """One scheduler tick: admit, decode one token for every active
         slot, retire finished requests. Returns #active slots."""
-        self._admit()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return 0
-        self.cache = {**self.cache,
-                      "len": jnp.asarray(self._uniform_len(), jnp.int32)}
-        tok, self.cache = self._step(self.params, self.tokens, self.cache)
+        self._ticks += 1
+        with jax.profiler.StepTraceAnnotation("batcher.tick",
+                                              step_num=self._ticks):
+            self._admit()
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            if active:
+                with span("batcher.decode", active=len(active),
+                          slots=self.num_slots):
+                    self._decode(active)
+        return len(active)
+
+    def _decode(self, active: List[int]) -> None:
+        with span("batcher.step"):
+            self.cache = {**self.cache,
+                          "len": jnp.asarray(self._uniform_len(), jnp.int32)}
+            tok, self.cache = self._step(self.params, self.tokens,
+                                         self.cache)
         self.tokens = tok
         for i in active:
             self._slot_len[i] += 1
             req = self.slots[i]
-            t = int(tok[i, 0])
+            with span("batcher.sync"):
+                t = int(tok[i, 0])
             req.generated.append(t)
             if t == self.eos_id or len(req.generated) >= req.max_new_tokens:
                 self._retire(req)
                 self.slots[i] = None
                 self._slot_len[i] = 0
-        return len(active)
 
     def run_until_drained(self, max_ticks: int = 10_000) -> List[Request]:
         """Step until queue and slots are empty; drain and return the

@@ -1,5 +1,5 @@
 """ContinuousBatcher regressions: admit-time retirement, drain
-stranding, clock injection.
+stranding, prompt bucketing.
 
 A stub model (scripted prefill logits + a ``tokens + 1`` decode step)
 stands in for the real JAX models, so these tests pin the *scheduler's*
@@ -10,9 +10,7 @@ host-side bookkeeping without paying model compilation:
   occupying a decode slot and appending tokens past EOS until the cap;
 - ``run_until_drained`` hitting ``max_ticks`` must raise
   :class:`SchedulerStalled` with the drained/stranded split instead of
-  silently returning a partial drain;
-- ``submitted_at`` / ``finished_at`` come from the injected clock so
-  batcher latency accounting can ride a virtual timeline.
+  silently returning a partial drain.
 """
 
 import numpy as np
@@ -55,26 +53,13 @@ def _stub_step(cfg):
     return step
 
 
-class _TickClock:
-    """Deterministic fake clock: each call advances by one tick."""
-
-    def __init__(self):
-        self.t = 0.0
-
-    def __call__(self) -> float:
-        self.t += 1.0
-        return self.t
-
-
 def _batcher(monkeypatch, first_token, *, eos_id=2, num_slots=2,
-             clock=None, stub=None):
+             stub=None):
     stub = stub or _StubApi(first_token)
     monkeypatch.setattr(sched, "api", stub)
     monkeypatch.setattr(sched, "make_serve_step", _stub_step)
-    kwargs = {} if clock is None else {"clock": clock}
     return sched.ContinuousBatcher(None, None, num_slots=num_slots,
-                                   max_len=32, eos_id=eos_id,
-                                   **kwargs), stub
+                                   max_len=32, eos_id=eos_id), stub
 
 
 def test_eos_on_prefill_retires_at_admit(monkeypatch):
@@ -91,7 +76,6 @@ def test_eos_on_prefill_retires_at_admit(monkeypatch):
     assert len(done) == 3
     for r in done:
         assert r.done and r.generated == [2]
-        assert r.finished_at > 0.0
     assert stub.prefills == 3
 
 
@@ -141,27 +125,6 @@ def test_run_until_drained_raises_on_stall(monkeypatch):
     assert len(done) == 1 and len(done[0].generated) == 10
 
 
-def test_injected_clock_stamps_requests(monkeypatch):
-    """submitted_at/finished_at must come from the injected clock (not
-    raw time.time) so batcher accounting can join a virtual timeline."""
-    clock = _TickClock()
-    b, _ = _batcher(monkeypatch, first_token=5, eos_id=2, clock=clock)
-    uid = b.submit(np.arange(4), max_new_tokens=2)
-    done = b.run_until_drained()
-    assert done[0].uid == uid
-    assert done[0].submitted_at == 1.0          # first clock tick
-    assert done[0].finished_at == clock.t       # last clock tick
-    assert done[0].finished_at > done[0].submitted_at
-
-
-def test_default_clock_is_wall_time(monkeypatch):
-    b, _ = _batcher(monkeypatch, first_token=2, eos_id=2)
-    b.submit(np.arange(4))
-    (r,) = b.run_until_drained()
-    import time
-    assert abs(r.submitted_at - time.time()) < 60.0
-
-
 def test_prefill_prompts_are_bucketed(monkeypatch):
     """Distinct prompt lengths collapse onto PREFILL_BUCKET multiples:
     the prefill jit site sees a bounded shape census instead of one
@@ -199,24 +162,3 @@ def test_bucketed_prefill_reads_true_last_position(monkeypatch):
     b.submit(np.arange(5), max_new_tokens=1)
     (r,) = b.run_until_drained()
     assert r.generated == [7]
-
-
-def test_jax_backend_normalizes_clock_objects():
-    """JaxBackend accepts either a bare callable or a serving-layer
-    clock object (.now(), e.g. VirtualClock) and threads the resulting
-    callable into its batchers."""
-    from repro.engine.backend import JaxBackend
-    from repro.serving.pipeline_server import VirtualClock
-
-    vc = VirtualClock(start=7.5)
-    be = JaxBackend(seed=0, clock=vc)
-    assert be.clock() == 7.5
-    vc.advance(1.0)
-    assert be.clock() == 8.5
-
-    ticks = iter((1.0, 2.0))
-    be2 = JaxBackend(seed=0, clock=lambda: next(ticks))
-    assert be2.clock() == 1.0 and be2.clock() == 2.0
-
-    import time
-    assert abs(JaxBackend(seed=0).clock() - time.time()) < 60.0
