@@ -269,7 +269,6 @@ def main() -> int:
 
     from repro.engine.backend import JaxBackend
     from repro.launch.serve import enable_compile_cache
-    from repro.serving.pipeline_server import MonotonicClock
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -282,7 +281,7 @@ def main() -> int:
     t0 = time.monotonic()
     failures: Dict[str, List[str]] = {}
     backend = JaxBackend(seed=SEED, max_new_tokens=MAX_NEW,
-                         decode_slots=SLOTS, clock=MonotonicClock(),
+                         decode_slots=SLOTS,
                          reduced=False)
     try:
         tickets, failures["serve"] = serve_phase(backend)

@@ -4,14 +4,18 @@ Fixed-slot design: a decode batch of ``num_slots`` sequences steps together;
 finished/empty slots are refilled from the queue between steps (prefill for
 the incoming request, cache splice into the slot). This is the standard
 TPU-serving shape: the decode step has a static (slots, 1) signature so it
-compiles once, and admission happens on the host between steps.
+compiles once, and admission happens on the host between steps. Admission
+runs two jitted programs as well: a prefill traced once per prompt bucket
+(the true length is a traced scalar) and a splice into a traced slot that
+donates the batch cache and the token column, so it updates them in place.
 
 The batcher marks its host work with profiler spans
 (``jax.profiler.TraceAnnotation``), which land on the trace's clock
 beside the device's planes and cost about a microsecond each when no
 profiler runs: ``batcher.tick`` (a ``StepTraceAnnotation`` numbered by
 tick), ``batcher.admit`` per request taken from the queue (``uid``,
-``prompt_len``, ``bucket``) holding ``batcher.prefill`` and
+``prompt_len``, ``bucket``) holding ``batcher.prefill`` (``traces``: how
+often the admission programs have been traced so far) and
 ``batcher.splice``, ``batcher.decode`` (``active``, ``slots``) holding
 ``batcher.step``, and ``batcher.sync`` around each device-to-host read.
 Every stat is a host integer known when its span opens.
@@ -35,9 +39,11 @@ span = jax.profiler.TraceAnnotation
 
 #: prompts right-pad to multiples of this before prefill, so the prefill
 #: jit site sees a handful of shapes instead of one per distinct prompt
-#: length. Causally safe: positions < the true length never attend to
-#: the pads, so the admitted token (read at true_len - 1) and the spliced
-#: cache rows [0, true_len) are bit-identical to the unpadded prefill.
+#: length. Exact only when a prompt fills its bucket. ``transformer.prefill``
+#: returns the logits of the last (padded) position alone, so the read at
+#: true_len - 1 clamps to the padded end; and an SSM layer's state runs on
+#: through the pads. Attention rows [0, true_len) are unaffected by the
+#: pads, but the cache's one ``len`` is the batch's (ROADMAP R2).
 PREFILL_BUCKET = 32
 
 
@@ -95,6 +101,10 @@ class ContinuousBatcher:
         self.tokens = jnp.zeros((num_slots, 1), jnp.int32)
         self._step = jax.jit(make_serve_step(cfg),
                              donate_argnums=SERVE_STEP_DONATE)
+        #: times the admission programs were traced (the jitted bodies
+        #: run only when they trace); constant once every bucket is warm
+        self.traces = 0
+        self._prefill, self._splice = self._admission_programs()
         self._uid = 0
         self._ticks = 0
         self.finished: List[Request] = []
@@ -128,6 +138,37 @@ class ContinuousBatcher:
                 if self._admit_one(req, slot):
                     break
 
+    def _admission_programs(self):
+        """The jitted prefill and splice of ``_admit_one``."""
+        cfg, max_len, num_slots = self.cfg, self.max_len, self.num_slots
+
+        def prefill(params, ids, true_len):
+            self.traces += 1
+            logits, cache1 = api.prefill(params, cfg, max_len, tokens=ids)
+            tok = jnp.argmax(logits[0, true_len - 1]).astype(jnp.int32)
+            return tok, cache1
+
+        def splice(cache, cache1, tokens, slot, tok):
+            self.traces += 1
+
+            def put(batch_leaf, one_leaf):
+                if batch_leaf.ndim == 0:
+                    return batch_leaf
+                # find the batch axis (with one slot, the first of size 1)
+                for ax in range(batch_leaf.ndim):
+                    if batch_leaf.shape[ax] == num_slots and \
+                            one_leaf.shape[ax] == 1:
+                        return jax.lax.dynamic_update_slice_in_dim(
+                            batch_leaf, one_leaf.astype(batch_leaf.dtype),
+                            slot, axis=ax)
+                return batch_leaf
+            new_cache = jax.tree.map(put, dict(cache), dict(cache1))
+            new_cache["len"] = cache["len"]  # batch len: see step
+            return new_cache, tokens.at[slot, 0].set(tok)
+
+        # the splice donates the batch cache and the token column
+        return jax.jit(prefill), jax.jit(splice, donate_argnums=(0, 2))
+
     def _admit_one(self, req: Request, slot: int) -> bool:
         """Prefill ``req`` and splice it into ``slot``; False when it
         retired at prefill and the slot is still free."""
@@ -139,14 +180,13 @@ class ContinuousBatcher:
                   bucket=blen):
             ids = np.zeros((1, blen), np.int32)
             ids[0, :true_len] = req.prompt
-            with span("batcher.prefill"):
-                logits, cache1 = api.prefill(self.params, self.cfg,
-                                             self.max_len,
-                                             tokens=jnp.asarray(ids))
+            with span("batcher.prefill", traces=self.traces):
+                tok, cache1 = self._prefill(self.params, ids,
+                                            np.int32(true_len))
             with span("batcher.sync"):
-                tok = int(jnp.argmax(logits[0, true_len - 1]))
-            req.generated.append(tok)
-            if tok == self.eos_id or \
+                t = int(tok)
+            req.generated.append(t)
+            if t == self.eos_id or \
                     len(req.generated) >= req.max_new_tokens:
                 # done at prefill: retire without touching the batch
                 # cache and offer the slot to the next queued request
@@ -154,24 +194,9 @@ class ContinuousBatcher:
                 return False
 
             # splice single-sequence cache into the batch cache
-            def splice(batch_leaf, one_leaf):
-                if batch_leaf.ndim == 0 or \
-                        one_leaf.shape == batch_leaf.shape:
-                    return batch_leaf
-                # find the batch axis: the axis where shapes differ
-                for ax in range(batch_leaf.ndim):
-                    if batch_leaf.shape[ax] == self.num_slots and \
-                            one_leaf.shape[ax] == 1:
-                        return jax.lax.dynamic_update_slice_in_dim(
-                            batch_leaf, one_leaf.astype(batch_leaf.dtype),
-                            slot, axis=ax)
-                return batch_leaf
             with span("batcher.splice"):
-                new_cache = jax.tree.map(splice, dict(self.cache),
-                                         dict(cache1))
-                new_cache["len"] = self.cache["len"]  # batch len: see step
-                self.cache = new_cache
-                self.tokens = self.tokens.at[slot, 0].set(tok)
+                self.cache, self.tokens = self._splice(
+                    self.cache, cache1, self.tokens, np.int32(slot), tok)
             self.slots[slot] = req
             self._slot_len[slot] = true_len
             return True

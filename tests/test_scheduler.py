@@ -1,17 +1,23 @@
 """ContinuousBatcher regressions: admit-time retirement, drain
-stranding, prompt bucketing.
+stranding, prompt bucketing, jitted admission.
 
 A stub model (scripted prefill logits + a ``tokens + 1`` decode step)
 stands in for the real JAX models, so these tests pin the *scheduler's*
-host-side bookkeeping without paying model compilation:
+host-side bookkeeping without paying model compilation. Admission runs
+jitted, so the stub's ``prefill`` runs once per trace: admissions are
+counted from the ``batcher.admit`` spans, traces from ``traces``.
 
 - a request whose prefill-generated first token is EOS (or whose
   ``max_new_tokens`` is 1) must retire at admit time instead of
   occupying a decode slot and appending tokens past EOS until the cap;
 - ``run_until_drained`` hitting ``max_ticks`` must raise
   :class:`SchedulerStalled` with the drained/stranded split instead of
-  silently returning a partial drain.
+  silently returning a partial drain;
+- admission traces its prefill once per bucket and its splice once, and
+  the splice writes one slot's rows and nothing else.
 """
+
+import contextlib
 
 import numpy as np
 import pytest
@@ -53,6 +59,20 @@ def _stub_step(cfg):
     return step
 
 
+class _CacheStub(_StubApi):
+    """A batch cache with a batch axis behind a layer axis, as the real
+    stacked caches have; a prefill fills its one row with ``sum(ids)``."""
+
+    def init_cache(self, cfg, num_slots, max_len):
+        return {"len": jnp.asarray(0, jnp.int32),
+                "rows": jnp.zeros((2, num_slots, 4), jnp.float32)}
+
+    def prefill(self, params, cfg, max_len, tokens):
+        logits, _ = super().prefill(params, cfg, max_len, tokens)
+        row = jnp.full((2, 1, 4), tokens.sum(), jnp.float32)
+        return logits, {"len": jnp.asarray(0, jnp.int32), "rows": row}
+
+
 def _batcher(monkeypatch, first_token, *, eos_id=2, num_slots=2,
              stub=None):
     stub = stub or _StubApi(first_token)
@@ -62,11 +82,28 @@ def _batcher(monkeypatch, first_token, *, eos_id=2, num_slots=2,
                                    max_len=32, eos_id=eos_id), stub
 
 
+def _record_spans(monkeypatch):
+    """Replace the batcher's profiler span with a recorder of
+    ``(name, stats)``."""
+    spans = []
+
+    def record(name, **stats):
+        spans.append((name, stats))
+        return contextlib.nullcontext()
+    monkeypatch.setattr(sched, "span", record)
+    return spans
+
+
+def _admits(spans):
+    return sum(name == "batcher.admit" for name, _ in spans)
+
+
 def test_eos_on_prefill_retires_at_admit(monkeypatch):
     """Regression: a request whose FIRST generated token is EOS used to
     occupy a decode slot and keep appending tokens until max_new_tokens;
     it must retire at admit time with exactly the one token."""
-    b, stub = _batcher(monkeypatch, first_token=2, eos_id=2)
+    spans = _record_spans(monkeypatch)
+    b, _ = _batcher(monkeypatch, first_token=2, eos_id=2)
     for _ in range(3):
         b.submit(np.arange(4), max_new_tokens=8)
     # one tick admits (and retires) everything: no decode step needed
@@ -76,7 +113,7 @@ def test_eos_on_prefill_retires_at_admit(monkeypatch):
     assert len(done) == 3
     for r in done:
         assert r.done and r.generated == [2]
-    assert stub.prefills == 3
+    assert _admits(spans) == 3
 
 
 def test_max_new_tokens_one_retires_at_admit(monkeypatch):
@@ -129,12 +166,15 @@ def test_prefill_prompts_are_bucketed(monkeypatch):
     """Distinct prompt lengths collapse onto PREFILL_BUCKET multiples:
     the prefill jit site sees a bounded shape census instead of one
     retrace per length."""
+    spans = _record_spans(monkeypatch)
     b, stub = _batcher(monkeypatch, first_token=5, eos_id=2, num_slots=2)
     for n in (1, 3, 7, 17, 31, 32):
         b.submit(np.arange(n), max_new_tokens=1)
     b.run_until_drained()
-    assert stub.prefills == 6
+    assert _admits(spans) == 6
     assert {s[1] for s in stub.prefill_shapes} == {32}
+    assert [st["bucket"] for name, st in spans
+            if name == "batcher.admit"] == [32] * 6
 
 
 def test_bucket_len_caps_at_max_len():
@@ -162,3 +202,70 @@ def test_bucketed_prefill_reads_true_last_position(monkeypatch):
     b.submit(np.arange(5), max_new_tokens=1)
     (r,) = b.run_until_drained()
     assert r.generated == [7]
+
+
+def test_one_bucket_traces_prefill_once(monkeypatch):
+    """Three admissions of one bucket, at three true lengths, run one
+    prefill program: the true length is traced, not baked in."""
+    spans = _record_spans(monkeypatch)
+    b, stub = _batcher(monkeypatch, first_token=5, eos_id=2)
+    for n in (3, 17, 32):
+        b.submit(np.arange(n), max_new_tokens=1)   # retire at prefill
+    done = b.run_until_drained()
+    assert [r.generated for r in done] == [[5]] * 3
+    assert _admits(spans) == 3
+    assert stub.prefills == 1 and b.traces == 1
+    # the stat reads the count when its span opens
+    assert [st["traces"] for name, st in spans
+            if name == "batcher.prefill"] == [0, 1, 1]
+
+
+def test_every_slot_admitted_traces_splice_once(monkeypatch):
+    b, _ = _batcher(monkeypatch, first_token=5, eos_id=2, num_slots=4,
+                    stub=_CacheStub(5))
+    for _ in range(4):
+        b.submit(np.arange(4), max_new_tokens=3)
+    assert b.step() == 4
+    assert all(r is not None for r in b.slots)
+    assert b.traces == 2            # one prefill, one splice
+    b.submit(np.arange(4), max_new_tokens=3)
+    b.run_until_drained()
+    assert b.traces == 2
+
+
+def test_retired_at_prefill_leaves_batch_cache_untouched(monkeypatch):
+    b, _ = _batcher(monkeypatch, first_token=5, eos_id=2,
+                    stub=_CacheStub(5))
+    b.cache = {**b.cache, "rows": jnp.arange(16, dtype=jnp.float32
+                                             ).reshape(2, 2, 4)}
+    rows, tokens = np.asarray(b.cache["rows"]), np.asarray(b.tokens)
+    cache_obj = b.cache
+    b.submit(np.arange(4), max_new_tokens=1)
+    assert b.step() == 0
+    assert b.cache is cache_obj
+    np.testing.assert_array_equal(np.asarray(b.cache["rows"]), rows)
+    np.testing.assert_array_equal(np.asarray(b.tokens), tokens)
+
+
+@pytest.mark.parametrize("slot", [0, 2, 3])
+def test_splice_writes_only_its_slot(monkeypatch, slot):
+    """The splice into slot k writes row k of every leaf and the token
+    column's entry k; every other slot stays bit-identical."""
+    b, _ = _batcher(monkeypatch, first_token=5, eos_id=2, num_slots=4,
+                    stub=_CacheStub(5))
+    before = np.random.default_rng(slot).standard_normal(
+        (2, 4, 4)).astype(np.float32)
+    b.cache = {**b.cache, "rows": jnp.asarray(before)}
+    b.tokens = jnp.arange(4, dtype=jnp.int32)[:, None] + 10
+    b.slots = [object() if i != slot else None for i in range(4)]
+    b.submit(np.arange(4), max_new_tokens=3)
+    b._admit()
+    assert b.slots[slot].uid == 1
+    after = np.asarray(b.cache["rows"])
+    others = [i for i in range(4) if i != slot]
+    np.testing.assert_array_equal(after[:, others], before[:, others])
+    np.testing.assert_array_equal(after[:, slot], np.full((2, 4), 6.0))
+    np.testing.assert_array_equal(
+        np.asarray(b.tokens)[:, 0],
+        [5 if i == slot else 10 + i for i in range(4)])
+    assert int(b.cache["len"]) == 0    # the batch length is carried
