@@ -9,9 +9,10 @@ and nothing of the program under test:
   served program seeds its own (same key splits, same distributions, the
   same bf16 rounding), so that the reference and the program hold equal
   weights without either handing the other an array;
-- ``layer``: the layer's forward pass over a whole sequence in plain
-  ``jax.numpy``: the SSM as its defining recurrence, one step per
-  position, never the chunked (SSD) form the program runs;
+- ``mixer``, and ``layer`` (pre-norm, mixer, unit residual): the forward
+  pass over a whole sequence in plain ``jax.numpy``: the SSM as its
+  defining recurrence, one step per position, never the chunked (SSD)
+  form the program runs;
 - the operation and byte counts of one token through the layer, and of
   the state one decode slot keeps.
 
@@ -133,11 +134,17 @@ def rmsnorm(x, w, eps):
 
 def layer(p, x, c, ar: Arith):
     """One Mamba2 layer with its residual: x (B, S, D) float32."""
-    b, s, _ = x.shape
+    return x + mixer(p, rmsnorm(x, p["pre_norm"], c["norm_eps"]), c, ar)
+
+
+def mixer(p, h, c, ar: Arith):
+    """The Mamba2 mixer from its normed input h (B, S, D) float32 to the
+    output projection, without the residual: a family whose layer scales
+    the branch or follows it with an FFN builds its layer round this."""
+    b, s, _ = h.shape
     d_in, nh, gn, conv_dim, _ = dims(c)
     hd, n, g = c["head_dim"], c["state_size"], c["n_groups"]
     eps = c["norm_eps"]
-    h = rmsnorm(x, p["pre_norm"], eps)
     zxbcdt = ar.mm("bsd,de->bse", h, p["in_proj"], -1, 0)
     z = zxbcdt[..., :d_in]
     xbc = zxbcdt[..., d_in:d_in + conv_dim]
@@ -169,7 +176,7 @@ def layer(p, x, c, ar: Arith):
     _, ys = jax.lax.scan(step, jnp.zeros((b, nh, hd, n), F32), time_major)
     y = ys.transpose(1, 0, 2, 3) + xs * p["D"][:, None]
     y = rmsnorm(y.reshape(b, s, d_in) * jax.nn.silu(z), p["gate_norm"], eps)
-    return x + ar.mm("bse,ed->bsd", y, p["out_proj"], -1, 0)
+    return ar.mm("bse,ed->bsd", y, p["out_proj"], -1, 0)
 
 
 def unembed(table, x, ar: Arith):

@@ -36,6 +36,23 @@ def init_weights(key, c):
     }
 
 
+def from_program(params, c):
+    """The served program's parameter tree under the reference's names:
+    the tests check it equal, leaf for leaf, to ``init_weights``."""
+    del c
+    layer = params["layers"]["slot0"]
+    m = layer["mamba"]
+    return {
+        "embed": params["embed"]["tokens"],
+        "final_norm": params["final_norm"]["scale"],
+        "layers": {"pre_norm": layer["norm"]["scale"],
+                   "in_proj": m["in_proj"], "conv_w": m["conv_w"],
+                   "conv_b": m["conv_b"], "A_log": m["A_log"], "D": m["D"],
+                   "dt_bias": m["dt_bias"], "gate_norm": m["norm"]["scale"],
+                   "out_proj": m["out_proj"]},
+    }
+
+
 def logits(w, tokens, c, ar: M.Arith, start: int):
     """Logits at positions ``start:`` of ``tokens`` (B, S), float32."""
     x = w["embed"][tokens].astype(M.F32)

@@ -25,11 +25,15 @@ DATA = Path(__file__).resolve().parent / "data"
 
 def smoke_limits(cell: str) -> Dict[str, Any]:
     """The limits that replace the cell's own at the program's smoke size,
-    where the gaps are smaller: ``data/smoke_limits/<traffic>.json``, set
-    from CPU readings that the file gives (a smaller output wants a
-    smaller limit). Every other limit is the cell's own."""
-    traffic = layout.cell(BENCH, cell)["traffic"]
-    with open(DATA / "smoke_limits" / f"{traffic}.json") as f:
+    where the gaps are smaller: ``data/smoke_limits/<cell>.json`` where
+    the cell has one, else ``<traffic>.json``, each set from CPU readings
+    that the file gives (a smaller output wants a smaller limit). Every
+    other limit is the cell's own."""
+    here = DATA / "smoke_limits"
+    path = here / f"{cell}.json"
+    if not path.exists():
+        path = here / f"{layout.cell(BENCH, cell)['traffic']}.json"
+    with open(path) as f:
         return json.load(f)["limits"]
 
 
@@ -110,14 +114,12 @@ def broken_step(fault: str):
     return make_broken
 
 
-def fault_cases(family: str):
-    """(cell, fault) for every cell of ``family``: the sound path and each
-    fault it can have. Half of the batch is left out only where the batch
-    is known to fill more than one slot: a closed backlog."""
+def fault_cases():
+    """(cell, fault) for every cell: the sound path and each fault it can
+    have. Half of the batch is left out only where the batch is known to
+    fill more than one slot: a closed backlog."""
     cases = []
     for w in BENCH["workloads"]:
-        if config_file(w["name"])["family"] != family:
-            continue
         cases += [(w["name"], f) for f in (None, "token", "state")]
         if layout.traffic(w["traffic"])["arrival"] == "closed":
             cases.append((w["name"], "half"))

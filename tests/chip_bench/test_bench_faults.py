@@ -1,4 +1,4 @@
-"""A whole run of each ssm cell, driven on the CPU at the program's
+"""A whole run of each cell, driven on the CPU at the program's
 smoke size with the chip check skipped, comes out correct; with the timed
 path broken underneath, ``correct`` comes out false. On the sound path
 the control, the reference in fp8 arithmetic, reads a widest gap at
@@ -15,12 +15,12 @@ import pytest
 from bench_helpers import check_run, fault_cases
 
 
-@pytest.mark.parametrize("cell,fault", fault_cases("ssm"))
+@pytest.mark.parametrize("cell,fault", fault_cases())
 def test_run_correct_only_on_the_sound_path(cell, fault, monkeypatch):
     check_run(cell, fault, monkeypatch)
 
 
-@pytest.mark.parametrize("cell", [c for c, f in fault_cases("ssm")
+@pytest.mark.parametrize("cell", [c for c, f in fault_cases()
                                   if f is None])
 def test_open_loop_run_is_correct(cell, monkeypatch):
     check_run(cell, None, monkeypatch, arrival="poisson")

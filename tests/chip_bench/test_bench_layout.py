@@ -5,7 +5,8 @@ import re
 
 import pytest
 
-from bench_helpers import BENCH, ROOT
+import bench_helpers
+from bench_helpers import BENCH, DATA, ROOT
 from benchmarks.chip import layout
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -34,6 +35,12 @@ def test_every_file_is_used():
         == named["limits"]
     assert {p.stem for p in (here / "metrics").glob("*.py")} \
         == named["metrics"]
+    # the CPU tests' data: smoke limits of a cell or a traffic mix, and
+    # recorded reference logits of a configuration
+    assert {p.stem for p in (DATA / "smoke_limits").glob("*.json")} \
+        <= named["limits"] | named["traffic"]
+    assert {p.stem for p in (DATA / "reference_logits").glob("*.json")} \
+        <= {c["name"] for c in BENCH["configs"]}
 
 
 @pytest.mark.parametrize("cell", _each("workloads"))
@@ -42,8 +49,8 @@ def test_each_cell_finds_its_files(cell):
     w = layout.cell(bench, cell)
     config = layout.config(bench, w["config"])
     fam = layout.family(config["family"])
-    for fn in ("init_weights", "logits", "decode_cost", "prefill_flops",
-               "param_bytes"):
+    for fn in ("init_weights", "logits", "from_program", "decode_cost",
+               "prefill_flops", "param_bytes"):
         assert callable(getattr(fam, fn))
     assert set(fam.PROGRAM_KEYS) <= set(config)
     t = layout.traffic(w["traffic"])
@@ -54,6 +61,19 @@ def test_each_cell_finds_its_files(cell):
     for kind in ("end_to_end", "per_layer"):
         for m in layout.cell_metrics(bench, cell, kind):
             assert callable(layout.metric_reader(m["name"]))
+
+
+def test_smoke_limits_prefer_the_cells_file(tmp_path, monkeypatch):
+    w = BENCH["workloads"][0]
+    here = tmp_path / "smoke_limits"
+    here.mkdir()
+    (here / f"{w['traffic']}.json").write_text(
+        '{"limits": {"max_logit_gap": 1}}')
+    monkeypatch.setattr(bench_helpers, "DATA", tmp_path)
+    assert bench_helpers.smoke_limits(w["name"]) == {"max_logit_gap": 1}
+    (here / f"{w['name']}.json").write_text(
+        '{"limits": {"max_logit_gap": 2}}')
+    assert bench_helpers.smoke_limits(w["name"]) == {"max_logit_gap": 2}
 
 
 @pytest.mark.parametrize("metric", _each("per_layer"))

@@ -1,19 +1,27 @@
 """Each family's plain float32 reference agrees with the served program
 at the program's smoke size, on the CPU: the weights it draws from a seed
-are the program's, leaf for leaf, and its logits are the program's full
-forward pass computed in float32."""
+are the program's, leaf for leaf (the family's ``from_program`` names
+them), and its logits are the program's full forward pass computed in
+float32. Where ``data/reference_logits/<config>.json`` records them, the
+reference's logits are also bit-identical to the recorded ones."""
+
+import hashlib
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench_helpers import BENCH, config_file, reduced_sizes
+from bench_helpers import BENCH, DATA, config_file, reduced_sizes
 from benchmarks.chip import layout
 from benchmarks.chip.families import _mamba2 as M
 
 #: one cell of each configuration
 CELLS = sorted({w["config"]: w["name"] for w in BENCH["workloads"]}.values())
+
+#: recorded reference logits, one file per configuration
+RECORDED = sorted(p.stem for p in (DATA / "reference_logits").glob("*.json"))
 
 
 def _program(sizes, seed):
@@ -24,22 +32,6 @@ def _program(sizes, seed):
     return cfg, api.init_params(jax.random.PRNGKey(seed), cfg)
 
 
-def _mamba_leaves(layer):
-    m = layer["mamba"]
-    return {"pre_norm": layer["norm"]["scale"], "in_proj": m["in_proj"],
-            "conv_w": m["conv_w"], "conv_b": m["conv_b"],
-            "A_log": m["A_log"], "D": m["D"], "dt_bias": m["dt_bias"],
-            "gate_norm": m["norm"]["scale"], "out_proj": m["out_proj"]}
-
-
-def _as_reference(params, sizes):
-    """The program's parameter tree under the reference's names."""
-    assert sizes["family"] == "ssm"
-    return {"embed": params["embed"]["tokens"],
-            "final_norm": params["final_norm"]["scale"],
-            "layers": _mamba_leaves(params["layers"]["slot0"])}
-
-
 @pytest.mark.parametrize("cell", CELLS)
 def test_reference_weights_are_the_programs(cell):
     sizes = reduced_sizes(config_file(cell))
@@ -47,12 +39,31 @@ def test_reference_weights_are_the_programs(cell):
     seed = 2**31 + 17
     _, params = _program(sizes, seed)
     ref = fam.init_weights(jax.random.PRNGKey(seed), sizes)
-    want = _as_reference(params, sizes)
+    want = fam.from_program(params, sizes)
+    # every leaf of the program's tree, each once: nothing left over
+    assert sorted(map(id, jax.tree.leaves(want))) \
+        == sorted(map(id, jax.tree.leaves(params)))
     assert jax.tree.structure(ref) == jax.tree.structure(want)
     for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(want)):
         assert a.dtype == b.dtype and a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("config", RECORDED)
+def test_reference_logits_are_the_recorded_ones(config):
+    with open(DATA / "reference_logits" / f"{config}.json") as f:
+        rec = json.load(f)
+    sizes = reduced_sizes(layout.config(BENCH, config))
+    fam = layout.family(sizes["family"])
+    toks = np.random.default_rng(rec["tokens_seed"]).integers(
+        3, sizes["vocab_size"], size=rec["tokens_shape"]).astype(np.int32)
+    w = fam.init_weights(jax.random.PRNGKey(rec["seed"]), sizes)
+    for mode, want in rec["sha256"].items():
+        with jax.default_matmul_precision("highest"):
+            lg = fam.logits(w, jnp.asarray(toks), sizes, M.Arith(mode), 0)
+        got = np.asarray(lg, np.float32)
+        assert hashlib.sha256(got.tobytes()).hexdigest() == want, mode
 
 
 @pytest.mark.parametrize("cell", CELLS)
