@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned architectures (the model pool M).
+"""Architecture registry: the model pool M (the 10 assigned architectures
+and granite-4.0-h-small).
 
 ``get_config(name)`` returns the full production config;
 ``get_config(name, reduced=True)`` a small same-family smoke config.
@@ -20,12 +21,14 @@ from repro.configs.granite_34b import CONFIG as granite_34b
 from repro.configs.mamba2_370m import CONFIG as mamba2_370m
 from repro.configs.zamba2_27b import CONFIG as zamba2_27b
 from repro.configs.internvl2_1b import CONFIG as internvl2_1b
+from repro.configs.granite_4_0_h_small import CONFIG as granite_4_0_h_small
 
 ARCHS: Dict[str, ModelConfig] = {
     c.name: c
     for c in [
         granite_moe_1b, grok_1_314b, whisper_medium, gemma2_9b, llama32_1b,
         gemma3_27b, granite_34b, mamba2_370m, zamba2_27b, internvl2_1b,
+        granite_4_0_h_small,
     ]
 }
 

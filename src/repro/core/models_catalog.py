@@ -1,4 +1,4 @@
-"""The model pool M: the 10 assigned architectures as serving endpoints.
+"""The model pool M: the registry's architectures as serving endpoints.
 
 This is the bridge that makes the assigned architectures native to the
 paper: MOAR's model-substitution directive chooses among *these* models,
@@ -63,6 +63,7 @@ _CONTEXT = {
     "mamba2-370m": 1_048_576,
     "zamba2-2.7b": 1_048_576,
     "internvl2-1b": 32_768,
+    "granite-4.0-h-small": 131_072,
 }
 
 # MRCR-style long-context retrieval (SSMs are cheap at long ctx but lossy
@@ -78,11 +79,12 @@ _LONG_SCORE = {
     "mamba2-370m": 0.40,
     "zamba2-2.7b": 0.60,
     "internvl2-1b": 0.50,
+    "granite-4.0-h-small": 0.70,  # assumed: 4 attention layers in 40
 }
 
 
 def analytic_price(arch: str) -> Dict[str, float]:
-    cfg = ARCHS[arch]
+    cfg = ARCHS[arch].deployment()
     n_act = cfg.active_params()
     n_tot = cfg.approx_params()
     # prefill: compute-bound, 2*N_active FLOPs/token at PREFILL_MFU
@@ -97,6 +99,9 @@ def analytic_price(arch: str) -> Dict[str, float]:
         full_layers, window_layers = 0, 0
     elif cfg.family == "hybrid":
         full_layers = cfg.num_layers // max(cfg.hybrid_attn_every, 1)
+        window_layers = 0
+    elif cfg.layer_kinds:
+        full_layers = cfg.layer_types.count("attention")
         window_layers = 0
     elif cfg.attn_pattern == "local_global":
         n_local, n_global = cfg.local_global_ratio
@@ -150,7 +155,8 @@ def catalog(artifact_dir: Optional[str] = None,
     prices = derive_prices(artifact_dir) if artifact_dir else \
         {a: analytic_price(a) for a in ARCHS}
     cards = {}
-    for arch, cfg in ARCHS.items():
+    for arch, share in ARCHS.items():
+        cfg = share.deployment()
         p = prices.get(arch) or analytic_price(arch)
         cards[arch] = ModelCard(
             name=arch,

@@ -41,7 +41,7 @@ from repro.core import pareto
 from repro.core.agent import (AgentContext, AgentPolicy, DirectiveStats,
                               ModelStats)
 from repro.core.directives import Directive, Target, applicable
-from repro.core.models_catalog import model_names
+from repro.core.models_catalog import catalog, model_names
 from repro.engine.executor import (CallCache, Executor, TransientLLMError,
                                    evaluation_cache_stats)
 from repro.engine.operators import (PipelineConfig, clone_pipeline,
@@ -317,10 +317,20 @@ class MOARSearch:
         assert root is not None, "initial pipeline failed to evaluate"
         # model variants of P0 as children: plan the whole sweep up front
         # (clamped to the remaining budget BEFORE the first evaluation),
-        # evaluate it as one batched session, commit in model order
+        # evaluate it as one batched session, commit in model order. The
+        # sweep widens the root at once, so it takes no more models than
+        # the root's widening admits when the budget is spent, with one
+        # round's slack: the cheapest of the pool
         variants: List[Tuple[str, PipelineConfig]] = []
         budget_left = self.budget - self.t
-        for m in self.models:
+        sweep = self.models
+        if self.progressive_widening:
+            cards = catalog()
+            n = widening_cap(self.budget) + self.round_width - 1
+            kept = set(sorted(sweep, key=lambda m: cards[m].price_in
+                              + cards[m].price_out)[:n])
+            sweep = [m for m in sweep if m in kept]
+        for m in sweep:
             variant = clone_pipeline(p0)
             changed = False
             for op in variant["operators"]:

@@ -82,6 +82,7 @@ _CAPABILITY = {
     "grok-1-314b": 0.95,
     "gemma3-27b": 0.92,
     "granite-34b": 0.90,
+    "granite-4.0-h-small": 0.89,  # assumed: a 32B-A9B hybrid MoE
     "gemma2-9b": 0.88,
     "zamba2-2.7b": 0.78,
     "llama3.2-1b": 0.74,

@@ -30,7 +30,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "window", "softcap", "block_q", "block_k",
-                     "interpret"))
+                     "interpret", "scale"))
 def flash_attention(
     q: jax.Array,  # (B, S, H, Hd)
     k: jax.Array,  # (B, S, K, Hd)
@@ -42,6 +42,7 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: Optional[bool] = None,
+    scale: Optional[float] = None,  # None -> head_dim ** -0.5
 ) -> jax.Array:
     b, s, h, hd = q.shape
     kh = k.shape[2]
@@ -83,7 +84,7 @@ def flash_attention(
         block_q=bq,
         block_k=bk,
         interpret=resolve_interpret(interpret),
-        scale=hd ** -0.5,
+        scale=hd ** -0.5 if scale is None else scale,
     )
     out = out[:, :, :, :s, :hd]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, hd)

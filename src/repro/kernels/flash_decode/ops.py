@@ -13,7 +13,8 @@ from repro.kernels.flash_decode.kernel import DEFAULT_BLOCK_S, flash_decode_gqa
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("softcap", "block_s", "interpret"))
+                   static_argnames=("softcap", "block_s", "interpret",
+                                    "scale"))
 def flash_decode(
     q: jax.Array,          # (B, 1, H, Hd)
     k: jax.Array,          # (B, S, K, Hd)
@@ -23,6 +24,7 @@ def flash_decode(
     softcap: float = 0.0,
     block_s: int = DEFAULT_BLOCK_S,
     interpret: Optional[bool] = None,
+    scale: Optional[float] = None,  # None -> head_dim ** -0.5
 ) -> jax.Array:
     b, _, h, hd = q.shape
     kh = k.shape[2]
@@ -56,5 +58,5 @@ def flash_decode(
     out = flash_decode_gqa(
         qg, k, v, jnp.asarray(valid_len, jnp.int32).reshape(1),
         softcap=softcap, block_s=bs, interpret=resolve_interpret(interpret),
-        scale=hd ** -0.5)
+        scale=hd ** -0.5 if scale is None else scale)
     return out[..., :hd].reshape(b, 1, h, hd)

@@ -58,7 +58,7 @@ OPTIMIZED_POLICY: Dict[str, Dict[str, Any]] = {
     "internvl2-1b": {"tp_min_shard": 64, "seq_parallel": True},
 }
 OPTIMIZED_CONFIG: Dict[str, Dict[str, Any]] = {
-    "grok-1-314b": {"moe_group_size": 64, "kv_cache_dtype": "int8"},
+    "grok-1-314b": {"kv_cache_dtype": "int8"},
     # int8 KV for the caches that crowd HBM at decode (gemma2 decode 83%,
     # long_500k 99%; zamba2 decode 92%) — ~1% rel logit error, top-1 stable
     "gemma2-9b": {"kv_cache_dtype": "int8"},

@@ -8,8 +8,8 @@ production mesh, printing the three roofline terms and the deltas vs the
 cell's baseline artifact. Results append to artifacts/perf/<cell>.jsonl so
 EXPERIMENTS.md §Perf can cite exact numbers.
 
-  PYTHONPATH=src python -m repro.launch.hillclimb --cell grok-1-314b:train_4k \
-      --exp moe_group_128
+  PYTHONPATH=src python -m repro.launch.hillclimb --cell gemma2-9b:train_4k \
+      --exp windowed_attention
   PYTHONPATH=src python -m repro.launch.hillclimb --list
 """
 
@@ -61,18 +61,6 @@ EXPERIMENTS = {
         "instead of masking full-S scores: local-layer attention FLOPs and "
         "score-tensor bytes drop ~S/(window+chunk)x",
         setup=_flag_windowed(True), teardown=_flag_windowed(False)),
-    # --- MoE dispatch overhead ---
-    "moe_group_128": Experiment(
-        "moe_group_128",
-        "dispatch-einsum FLOPs per token = 2*gs*k*cf*D (group-size-"
-        "proportional); gs 512->128 cuts per-device dispatch compute ~4x "
-        "at equal expert compute",
-        config={"moe_group_size": 128}),
-    "moe_group_64": Experiment(
-        "moe_group_64",
-        "gs 128->64 continues the dispatch reduction (diminishing returns "
-        "expected once expert FFN dominates)",
-        config={"moe_group_size": 64}),
     # --- sequence parallelism ---
     "seq_parallel": Experiment(
         "seq_parallel",

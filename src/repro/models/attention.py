@@ -1,4 +1,5 @@
-"""Grouped-query attention with RoPE, sliding windows, softcap and KV caches.
+"""Grouped-query attention with RoPE (or none), sliding windows, softcap and
+KV caches.
 
 One attention implementation serves every assigned architecture:
 
@@ -10,6 +11,9 @@ One attention implementation serves every assigned architecture:
   layers may use a ring-buffer cache of ``window`` size (see serving/kv_cache).
 - cross-attention (whisper decoder) reuses the same block with ``kv_x`` set
   and RoPE disabled on keys.
+- ``cfg.position_embedding == "nope"`` (granite-4.0-h) leaves q and k
+  unrotated on every path, and ``cfg.resolved_attn_scale`` scales the
+  scores on every path.
 """
 
 from __future__ import annotations
@@ -93,12 +97,14 @@ def attend(
     window=None,
     attn_softcap: float = 0.0,
     kv_valid: Optional[jax.Array] = None,  # (B,K) bool — cache validity
+    scale: Optional[float] = None,  # None -> head_dim ** -0.5
 ) -> jax.Array:
     """Reference masked attention. Returns (B,Q,H,Hd)."""
     num_heads = q.shape[2]
     k = _expand_kv(k, num_heads)
     v = _expand_kv(v, num_heads)
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     scores = L.softcap(scores, attn_softcap)
     scores = mask_logits(scores, q_pos, k_pos, causal=causal, window=window)
@@ -146,6 +152,7 @@ def attend_chunked(
     window=None,
     attn_softcap: float = 0.0,
     chunk: int = 512,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Query-chunked attention: bounds the live (B,H,chunk,Sk) score tensor
     instead of materializing (B,H,S,Sk). The chunk body is rematerialized
@@ -181,7 +188,7 @@ def attend_chunked(
         else:
             kc, vc, kpc = k, v, k_pos
         out = attend(qc, kc, vc, q_pos=qpc, k_pos=kpc, causal=causal,
-                     window=window, attn_softcap=attn_softcap)
+                     window=window, attn_softcap=attn_softcap, scale=scale)
         return carry, out
 
     qs = q.reshape(b, nq, chunk, h, hd).transpose(1, 0, 2, 3, 4)
@@ -212,9 +219,11 @@ def attn_prefill(
     decode cache from the prefill pass."""
     q, k, v = project_qkv(params, cfg, x, kv_x)
     is_cross = kv_x is not None
+    scale = cfg.resolved_attn_scale
     if not is_cross:
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
+        if cfg.position_embedding == "rope":
+            q = L.apply_rope(q, positions, cfg.rope_theta)
+            k = L.apply_rope(k, positions, cfg.rope_theta)
         kv_pos = positions
     else:
         kv_pos = (kv_positions if kv_positions is not None
@@ -226,6 +235,7 @@ def attn_prefill(
             causal=True,
             window=int(window) if isinstance(window, int) else None,
             softcap=cfg.attn_softcap,
+            scale=scale,
         )
     else:
         q_pos2 = positions if positions.ndim == 2 else positions[None]
@@ -236,7 +246,7 @@ def attn_prefill(
                 q_pos=jnp.broadcast_to(q_pos2, q.shape[:2]),
                 k_pos=jnp.broadcast_to(k_pos2, k.shape[:2]),
                 causal=causal, window=window,
-                attn_softcap=cfg.attn_softcap, chunk=CHUNK_Q)
+                attn_softcap=cfg.attn_softcap, chunk=CHUNK_Q, scale=scale)
         else:
             out = attend(
                 q, k, v,
@@ -245,6 +255,7 @@ def attn_prefill(
                 causal=causal,
                 window=window,
                 attn_softcap=cfg.attn_softcap,
+                scale=scale,
             )
     out = out.reshape(*out.shape[:-2], -1)
     return jnp.einsum("...e,ed->...d", out, params["wo"]), (k, v)
@@ -267,13 +278,13 @@ def attend_grouped_decode(
     window,
     attn_softcap: float,
     kv_valid: Optional[jax.Array],  # (B, S)
+    scale: float,
 ) -> jax.Array:
     from repro.models.partitioning import shard_activation
     b, _, h, hd = q.shape
     kh = k.shape[2]
     g = h // kh
     qg = q.reshape(b, kh, g, hd)
-    scale = hd ** -0.5
     scores = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     scores = L.softcap(scores, attn_softcap)
@@ -316,8 +327,10 @@ def attn_decode(
     smax = cache_k.shape[1]
     pos = jnp.full((b, 1), cache_len, dtype=jnp.int32)  # query abs position
     q, k_new, v_new = project_qkv(params, cfg, x)
-    q = L.apply_rope(q, pos, cfg.rope_theta)
-    k_new = L.apply_rope(k_new, pos, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = L.apply_rope(q, pos, cfg.rope_theta)
+        k_new = L.apply_rope(k_new, pos, cfg.rope_theta)
+    scale = cfg.resolved_attn_scale
 
     slot = jnp.where(ring, cache_len % smax, jnp.minimum(cache_len, smax - 1))
     cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k_new.astype(cache_k.dtype), slot, axis=1)
@@ -338,7 +351,7 @@ def attn_decode(
         from repro.kernels.flash_decode import ops as fd_ops
         out = fd_ops.flash_decode(
             q, cache_k, cache_v, cache_len + 1,
-            softcap=cfg.attn_softcap,
+            softcap=cfg.attn_softcap, scale=scale,
         ).reshape(b, 1, cfg.num_heads, -1)
         out = out.reshape(b, 1, -1)
         return (jnp.einsum("...e,ed->...d", out, params["wo"]),
@@ -354,6 +367,7 @@ def attn_decode(
             window=window,
             attn_softcap=cfg.attn_softcap,
             kv_valid=jnp.broadcast_to(valid[None], (b, smax)),
+            scale=scale,
         )
     else:
         out = attend(
@@ -364,6 +378,7 @@ def attn_decode(
             window=window,
             attn_softcap=cfg.attn_softcap,
             kv_valid=jnp.broadcast_to(valid[None], (b, smax)),
+            scale=scale,
         )
     out = out.reshape(b, 1, -1)
     return jnp.einsum("...e,ed->...d", out, params["wo"]), (cache_k, cache_v)
@@ -392,8 +407,10 @@ def attn_decode_cached(
     smax = lc["k"].shape[1]
     pos = jnp.full((b, 1), cache_len, dtype=jnp.int32)
     q, k_new, v_new = project_qkv(params, cfg, x)
-    q = L.apply_rope(q, pos, cfg.rope_theta)
-    k_new = L.apply_rope(k_new, pos, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = L.apply_rope(q, pos, cfg.rope_theta)
+        k_new = L.apply_rope(k_new, pos, cfg.rope_theta)
+    scale = cfg.resolved_attn_scale
 
     slot = jnp.where(ring, cache_len % smax, jnp.minimum(cache_len, smax - 1))
     qk, sk = quantize_kv(k_new)
@@ -422,13 +439,13 @@ def attn_decode_cached(
             q, k_full, v_full, q_pos=pos,
             k_pos=k_pos[None].astype(jnp.int32), window=window,
             attn_softcap=cfg.attn_softcap,
-            kv_valid=jnp.broadcast_to(valid[None], (b, smax)))
+            kv_valid=jnp.broadcast_to(valid[None], (b, smax)), scale=scale)
     else:
         out = attend(
             q, k_full, v_full, q_pos=pos,
             k_pos=k_pos[None].astype(jnp.int32), causal=True,
             window=window, attn_softcap=cfg.attn_softcap,
-            kv_valid=jnp.broadcast_to(valid[None], (b, smax)))
+            kv_valid=jnp.broadcast_to(valid[None], (b, smax)), scale=scale)
     out = out.reshape(b, 1, -1)
     return (jnp.einsum("...e,ed->...d", out, params["wo"]),
             {"k": ck, "v": cv, "k_scale": csk, "v_scale": csv})
@@ -452,6 +469,7 @@ def attn_cross_decode(
         causal=False,
         window=None,
         attn_softcap=cfg.attn_softcap,
+        scale=cfg.resolved_attn_scale,
     )
     out = out.reshape(x.shape[0], 1, -1)
     return jnp.einsum("...e,ed->...d", out, params["wo"])

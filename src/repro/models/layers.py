@@ -34,15 +34,19 @@ def dtype_of(name: str):
 # --------------------------------------------------------------------------
 
 
-def dense_init(key, in_dim: int, out_dim: int, dtype) -> jax.Array:
-    """Truncated-normal fan-in init (matches common LM practice)."""
-    std = 1.0 / np.sqrt(in_dim)
-    return (jax.random.truncated_normal(key, -2.0, 2.0, (in_dim, out_dim), jnp.float32)
+def trunc_normal(key, shape, std: float, dtype) -> jax.Array:
+    """A normal truncated at two deviations, scaled by ``std``."""
+    return (jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
             * std).astype(dtype)
 
 
-def embed_init(key, vocab: int, dim: int, dtype) -> jax.Array:
-    return (jax.random.normal(key, (vocab, dim), jnp.float32) * 0.02).astype(dtype)
+def dense_init(key, in_dim: int, out_dim: int, dtype) -> jax.Array:
+    """Truncated-normal fan-in init (matches common LM practice)."""
+    return trunc_normal(key, (in_dim, out_dim), 1.0 / np.sqrt(in_dim), dtype)
+
+
+def embed_init(key, vocab: int, dim: int, dtype, std: float = 0.02) -> jax.Array:
+    return (jax.random.normal(key, (vocab, dim), jnp.float32) * std).astype(dtype)
 
 
 def zeros_init(shape, dtype) -> jax.Array:
@@ -140,7 +144,11 @@ def gelu_mlp(params, x: jax.Array) -> jax.Array:
 
 def init_embedding(key, cfg: ModelConfig):
     dtype = dtype_of(cfg.param_dtype)
-    params = {"tokens": embed_init(key, cfg.vocab_size, cfg.d_model, dtype)}
+    # drawn so that the embedding, once multiplied, has std 0.02: at
+    # granite's x12, a table of std 0.02 outweighs every layer's branch and
+    # the tied head then ranks the input token first at every position
+    params = {"tokens": embed_init(key, cfg.vocab_size, cfg.d_model, dtype,
+                                   std=0.02 / cfg.embedding_multiplier)}
     if not cfg.tie_embeddings:
         k2 = jax.random.fold_in(key, 1)
         params["unembed"] = embed_init(k2, cfg.vocab_size, cfg.d_model, dtype)
@@ -157,6 +165,8 @@ def embed(params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
 def unembed(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     table = params.get("unembed", params["tokens"])
     logits = jnp.einsum("...d,vd->...v", x, table).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.final_softcap > 0.0:
         c = cfg.final_softcap
         logits = c * jnp.tanh(logits / c)

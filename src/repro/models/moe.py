@@ -1,142 +1,152 @@
-"""Mixture-of-Experts layer (granite-moe 32e top-8, grok-1 8e top-2).
+"""Mixture-of-Experts layer (granite-moe 32e top-8, grok-1 8e top-2,
+granite-4.0-h 72e top-10 with a shared expert).
 
-Implementation is the grouped dense-dispatch ("einsum MoE") formulation:
-tokens are split into groups, and within each group a (S_g, E, C) one-hot
-dispatch tensor routes tokens to per-expert capacity slots. This formulation
+The layer is told which experts it holds: ``cfg.experts_held`` of them,
+from ``cfg.moe_expert_offset``, this chip's share of an expert-parallel
+layer (all of them unless the config says otherwise). The router keeps its
+published width and routes every token over all ``cfg.num_experts``: the
+top-k logits, in float32, gated by a softmax over those k. A token gets
+each held expert it chose, weighted by its gate; no token is ever dropped,
+at any batch size, and a token none of whose experts are held gets nothing
+from the routed part here: what the absent experts would add is another
+chip's share. A shared expert, where the config has one, runs on every
+token.
 
-- keeps every shape static (jit/scan friendly),
-- shards naturally: token/group axes follow the batch ("data") sharding and
-  the expert axis E shards over the "model" mesh axis (expert parallelism),
-- has dispatch-einsum overhead O(N * G * k * cf * D) — <1% of expert-FFN
-  FLOPs at the default group size.
+Two layouts compute the same function, chosen by the config:
 
-An alternative fused expert-FFN Pallas kernel operates on the dispatched
-(E, C, D) layout (see kernels/moe_ffn) and is selected via ``cfg.use_pallas``.
+- grouped (``top_k < held``): each token's (token, expert) pairs that land
+  on a held expert, sorted by expert, go through one ragged matmul per
+  projection (``jax.lax.ragged_dot``). A token reaches at most
+  ``min(top_k, held)`` held experts, so the rows are ``N * top_k``: the
+  routed FLOPs of the top-k, with no capacity to drop at.
+- dense (``top_k >= held``): every held expert runs on every token and its
+  output is weighted by the token's gate for it (0 where the token did not
+  choose it). That is no more rows than the grouped layout would take, and
+  at decode the weights are read once for the whole batch. With
+  ``cfg.use_pallas`` the experts run in the fused expert-FFN kernel (see
+  kernels/moe_ffn), each over the whole token batch.
+
+The named scopes ``ffn.router``, ``ffn.experts`` and ``ffn.shared`` mark
+the three parts in the compiled program's op metadata.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.config import ModelConfig
 from repro.models import layers as L
 
-DEFAULT_GROUP = 512
-DEFAULT_CAPACITY_FACTOR = 1.25
-
 
 def init_moe(key, cfg: ModelConfig):
+    """The router over all experts, the held experts' weights and the shared
+    expert. Expert ``i`` of a projection is drawn from ``fold_in(k, i)``,
+    so a share draws its own experts alone and the shares of one key tile
+    the uncut layer."""
     dtype = L.dtype_of(cfg.param_dtype)
-    fe = cfg.resolved_moe_d_ff
+    fe, d, e = cfg.resolved_moe_d_ff, cfg.d_model, cfg.num_experts
+    ids = cfg.moe_expert_offset + jnp.arange(cfg.experts_held)
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    return {
-        "router": L.dense_init(k1, cfg.d_model, cfg.num_experts, jnp.float32),
-        "w_gate": (L.dense_init(k2, cfg.d_model, cfg.num_experts * fe, dtype)
-                   .reshape(cfg.d_model, cfg.num_experts, fe).transpose(1, 0, 2)),
-        "w_up": (L.dense_init(k3, cfg.d_model, cfg.num_experts * fe, dtype)
-                 .reshape(cfg.d_model, cfg.num_experts, fe).transpose(1, 0, 2)),
-        "w_down": (L.dense_init(k4, fe * cfg.num_experts, cfg.d_model, dtype)
-                   .reshape(cfg.num_experts, fe, cfg.d_model)),
+
+    def experts(k, fan_in, fan_out, std):
+        return jax.vmap(lambda i: L.trunc_normal(
+            jax.random.fold_in(k, i), (fan_in, fan_out), std, dtype))(ids)
+
+    params = {
+        "router": L.dense_init(k1, d, e, jnp.float32),
+        "w_gate": experts(k2, d, fe, 1.0 / np.sqrt(d)),
+        "w_up": experts(k3, d, fe, 1.0 / np.sqrt(d)),
+        # scaled by the fan-in of the whole layer's down projection
+        "w_down": experts(k4, fe, d, 1.0 / np.sqrt(e * fe)),
     }
+    if cfg.shared_d_ff:
+        params["shared"] = L.init_mlp(jax.random.fold_in(key, 4), d,
+                                      cfg.shared_d_ff, dtype)
+    return params
 
 
 def router_topk(params, cfg: ModelConfig, x: jax.Array):
-    """Top-k routing with softmax-renormalized gates.
+    """Top-k routing over all experts, gated by a softmax over the top-k
+    logits (the softmax over all, renormalised over the top k, is the same
+    function).
 
     x: (N, D) -> (assign (N,k) int32, gates (N,k) f32, probs (N,E) f32)
     """
     logits = jnp.einsum("nd,de->ne", x.astype(jnp.float32), params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, assign = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    gates = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-    return assign.astype(jnp.int32), gates, probs
+    top, assign = jax.lax.top_k(logits, cfg.num_experts_per_tok)
+    return (assign.astype(jnp.int32), jax.nn.softmax(top, axis=-1),
+            jax.nn.softmax(logits, axis=-1))
 
 
-def _dispatch_combine(assign, gates, num_experts: int, capacity: int, dtype):
-    """Build (S, E, C) dispatch/combine tensors for one token group.
-
-    Priority is slot-major (all top-1 choices claim capacity before top-2),
-    matching standard switch-transformer dispatch semantics.
-    """
-    s, k = assign.shape
-    oh = jax.nn.one_hot(assign, num_experts, dtype=jnp.int32)  # (S,k,E)
-    oh_prio = jnp.transpose(oh, (1, 0, 2)).reshape(k * s, num_experts)
-    pos = jnp.cumsum(oh_prio, axis=0) - oh_prio  # position within each expert
-    pos = pos.reshape(k, s, num_experts).transpose(1, 0, 2)  # (S,k,E)
-    pos_sel = jnp.sum(pos * oh, axis=-1)  # (S,k)
-    keep = (pos_sel < capacity).astype(dtype)
-    slot_oh = jax.nn.one_hot(pos_sel, capacity, dtype=dtype)  # (S,k,C)
-    disp = jnp.einsum("ske,skc,sk->sec", oh.astype(dtype), slot_oh, keep)
-    comb = jnp.einsum("ske,skc,sk->sec", oh.astype(dtype), slot_oh,
-                      keep * gates.astype(dtype))
-    return disp, comb
+def held_gates(assign, gates, cfg: ModelConfig) -> jax.Array:
+    """(N, held) f32: each token's gate for each held expert, 0 where the
+    token did not choose it."""
+    ids = cfg.moe_expert_offset + jnp.arange(cfg.experts_held)
+    hit = assign[:, :, None] == ids[None, None, :]            # (N, k, held)
+    return jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
 
 
-def expert_capacity(tokens_per_group: int, cfg: ModelConfig,
-                    capacity_factor: float = 0.0) -> int:
-    cf = capacity_factor or cfg.moe_capacity_factor
-    c = math.ceil(tokens_per_group * cfg.num_experts_per_tok
-                  * cf / cfg.num_experts)
-    return max(4, min(c, tokens_per_group))
-
-
-def _expert_ffn(params, xin: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """xin: (G, E, C, D) -> (G, E, C, D). SwiGLU per expert."""
+def _dense(params, cfg: ModelConfig, x, assign, gates) -> jax.Array:
+    """x (N, D) -> (N, D): every held expert's SwiGLU on every token,
+    weighted by the token's gate for it."""
+    comb = held_gates(assign, gates, cfg).astype(x.dtype)
     if cfg.use_pallas:
         from repro.kernels.moe_ffn import ops as moe_ops
-        g, e, c, d = xin.shape
-        out = moe_ops.expert_ffn(
-            xin.reshape(g * e, c, d).reshape(g, e, c, d),  # no-op, kept for clarity
-            params["w_gate"], params["w_up"], params["w_down"],
-        )
-        return out
-    gate = jnp.einsum("gecd,edf->gecf", xin, params["w_gate"])
-    up = jnp.einsum("gecd,edf->gecf", xin, params["w_up"])
-    return jnp.einsum("gecf,efd->gecd", jax.nn.silu(gate) * up, params["w_down"])
+        n, d = x.shape
+        xin = jnp.broadcast_to(x[None, None], (1, cfg.experts_held, n, d))
+        out = moe_ops.expert_ffn(xin, params["w_gate"], params["w_up"],
+                                 params["w_down"])[0]
+    else:
+        gate = jnp.einsum("nd,edf->enf", x, params["w_gate"])
+        up = jnp.einsum("nd,edf->enf", x, params["w_up"])
+        out = jnp.einsum("enf,efd->end", jax.nn.silu(gate) * up,
+                         params["w_down"])
+    return jnp.einsum("end,ne->nd", out, comb)
 
 
-def moe_ffn(
-    params,
-    cfg: ModelConfig,
-    x: jax.Array,  # (B, S, D)
-    *,
-    group_size: Optional[int] = None,
-    capacity_factor: float = 0.0,
-) -> Tuple[jax.Array, jax.Array]:
-    """Returns (output (B,S,D), load-balancing aux loss scalar)."""
+def _grouped(params, cfg: ModelConfig, x, assign, gates) -> jax.Array:
+    """x (N, D) -> (N, D): each token through the held experts it chose
+    alone, its pairs sorted by expert into one ragged matmul a
+    projection."""
+    n, d = x.shape
+    k, held = cfg.num_experts_per_tok, cfg.experts_held
+    local = assign.reshape(-1) - cfg.moe_expert_offset           # (N*k,)
+    is_held = (local >= 0) & (local < held)
+    group = jnp.where(is_held, local, held)   # pairs held elsewhere: last
+    order = jnp.argsort(group, stable=True)
+    rows = n * min(k, held)   # every held pair, whatever the routing
+    sizes = jnp.bincount(group, length=held + 1)[:held]
+    xs = x[order[:rows] // k]
+    h = (jax.nn.silu(jax.lax.ragged_dot(xs, params["w_gate"], sizes))
+         * jax.lax.ragged_dot(xs, params["w_up"], sizes))
+    out = jax.lax.ragged_dot(h, params["w_down"], sizes)          # (rows, D)
+    # back to (token, choice) order; a pair held elsewhere reads 0
+    pair = jnp.take(out, jnp.argsort(order), axis=0, mode="fill",
+                    fill_value=0)
+    pair = jnp.where(is_held[:, None], pair, 0).reshape(n, k, d)
+    return jnp.einsum("nkd,nk->nd", pair, gates.astype(x.dtype))
+
+
+def moe_ffn(params, cfg: ModelConfig, x: jax.Array
+            ) -> Tuple[jax.Array, jax.Array]:
+    """x (B, S, D) -> (output (B,S,D), load-balancing aux loss scalar)."""
     b, s, d = x.shape
-    n = b * s
-    gs = min(group_size or cfg.moe_group_size, n)
-    # pad token count to a multiple of the group size
-    n_pad = math.ceil(n / gs) * gs
-    flat = x.reshape(n, d)
-    if n_pad != n:
-        flat = jnp.pad(flat, ((0, n_pad - n), (0, 0)))
-    ng = n_pad // gs
-
-    assign, gates, probs = router_topk(params, cfg, flat)
-
-    # aux loss on unpadded tokens (switch-transformer load balancing)
-    tok_oh = jax.nn.one_hot(assign[:n, 0], cfg.num_experts, dtype=jnp.float32)
-    frac_tokens = jnp.mean(tok_oh, axis=0)
-    frac_probs = jnp.mean(probs[:n], axis=0)
-    aux = cfg.num_experts * jnp.sum(frac_tokens * frac_probs)
-
-    cap = expert_capacity(gs, cfg, capacity_factor)
-
-    assign_g = assign.reshape(ng, gs, -1)
-    gates_g = gates.reshape(ng, gs, -1)
-    disp, comb = jax.vmap(
-        lambda a, g: _dispatch_combine(a, g, cfg.num_experts, cap, x.dtype)
-    )(assign_g, gates_g)
-
-    xg = flat.reshape(ng, gs, d)
-    xin = jnp.einsum("gsec,gsd->gecd", disp, xg)
-    xout = _expert_ffn(params, xin, cfg)
-    yg = jnp.einsum("gsec,gecd->gsd", comb, xout)
-    y = yg.reshape(n_pad, d)[:n].reshape(b, s, d)
-    return y, aux
+    flat = x.reshape(b * s, d)
+    with jax.named_scope("ffn.router"):
+        assign, gates, probs = router_topk(params, cfg, flat)
+        # switch-transformer load balancing over the router's experts
+        frac_tokens = jnp.mean(jax.nn.one_hot(assign[:, 0], cfg.num_experts,
+                                              dtype=jnp.float32), axis=0)
+        aux = cfg.num_experts * jnp.sum(frac_tokens * jnp.mean(probs, 0))
+    with jax.named_scope("ffn.experts"):
+        experts = (_grouped if cfg.num_experts_per_tok < cfg.experts_held
+                   else _dense)
+        y = experts(params, cfg, flat, assign, gates)
+    if "shared" in params:
+        with jax.named_scope("ffn.shared"):
+            y = y + L.mlp(params["shared"], flat)
+    return y.reshape(b, s, d), aux

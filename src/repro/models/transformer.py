@@ -3,8 +3,10 @@
 Layer layout
 ------------
 Layers are organized into *periods* of a repeating pattern (gemma2 = [local,
-global], gemma3 = [local x5, global], zamba2 = [mamba x N, shared-attn]), and
-the model scans over periods with per-slot stacked parameters:
+global], gemma3 = [local x5, global], zamba2 = [mamba x N, shared-attn],
+granite-4.0-h = ``cfg.layer_kinds``, e.g. [mamba x5, attn, mamba x4], each
+slot a mixer followed by its own MoE), and the model scans over periods with
+per-slot stacked parameters:
 
     params["layers"]["slot{i}"]  : pytree stacked along axis 0, (n_full, ...)
     params["tail"][j]            : unstacked params for the L % period tail
@@ -17,7 +19,13 @@ slots carry full-length caches — the memory trick that makes gemma-family
 ``long_500k`` decode feasible.
 
 Caches mirror the layout: ``cache["slots"]["slot{i}"]`` stacked (n_full, ...)
-consumed/produced as scan xs/ys, plus ``cache["tail"]`` and ``cache["shared"]``.
+consumed/produced as scan xs/ys, plus ``cache["tail"]`` and ``cache["shared"]``;
+a period of mixed kinds keeps KV and SSM state side by side there.
+
+Each residual branch joins the stream scaled by ``cfg.residual_multiplier``
+(1 leaves it as it is). The named scopes ``mixer.mamba`` and
+``mixer.attention`` mark the mixers in the compiled program's op metadata
+(the FFN's parts are marked in ``moe.py``).
 """
 
 from __future__ import annotations
@@ -46,6 +54,8 @@ Cache = Dict[str, Any]
 
 def layer_pattern(cfg: ModelConfig) -> List[str]:
     """Slot kinds for one period: 'attn_local' | 'attn_global' | 'mamba'."""
+    if cfg.layer_kinds:
+        return list(cfg.layer_kinds)
     if cfg.family == "ssm":
         return ["mamba"]
     if cfg.family == "hybrid":
@@ -75,20 +85,29 @@ def slot_window(cfg: ModelConfig, kind: str) -> Optional[int]:
 # --------------------------------------------------------------------------
 
 
+def _init_ffn(key, cfg: ModelConfig) -> Params:
+    p: Params = {"norm_mlp": L.init_rmsnorm(cfg.d_model)}
+    if cfg.is_moe:
+        p["moe"] = M.init_moe(key, cfg)
+    else:
+        p["mlp"] = L.init_mlp(key, cfg.d_model, cfg.d_ff,
+                              L.dtype_of(cfg.param_dtype))
+    return p
+
+
 def _init_layer(key, cfg: ModelConfig, kind: str) -> Params:
     if kind == "mamba":
-        return {"norm": L.init_rmsnorm(cfg.d_model),
-                "mamba": S.init_mamba(key, cfg)}
+        p = {"norm": L.init_rmsnorm(cfg.d_model),
+             "mamba": S.init_mamba(key, cfg)}
+        if cfg.layer_kinds:  # every slot of such a period has its FFN
+            p.update(_init_ffn(jax.random.fold_in(key, 1), cfg))
+        return p
     k1, k2 = jax.random.split(key)
     p: Params = {
         "norm_attn": L.init_rmsnorm(cfg.d_model),
         "attn": A.init_attention(k1, cfg),
-        "norm_mlp": L.init_rmsnorm(cfg.d_model),
+        **_init_ffn(k2, cfg),
     }
-    if cfg.is_moe:
-        p["moe"] = M.init_moe(k2, cfg)
-    else:
-        p["mlp"] = L.init_mlp(k2, cfg.d_model, cfg.d_ff, L.dtype_of(cfg.param_dtype))
     if cfg.post_norms:
         p["post_attn"] = L.init_rmsnorm(cfg.d_model)
         p["post_mlp"] = L.init_rmsnorm(cfg.d_model)
@@ -106,12 +125,16 @@ def _init_shared_block(key, cfg: ModelConfig) -> Params:
     }
 
 
-def _apply_attn_layer_full(p: Params, cfg: ModelConfig, x, positions, window):
-    h, kv = A.attn_prefill(p["attn"], cfg, L.rmsnorm(p["norm_attn"], x, cfg.norm_eps),
-                           positions, window=window)
-    if cfg.post_norms:
-        h = L.rmsnorm(p["post_attn"], h, cfg.norm_eps)
-    x = x + h
+def _join(cfg: ModelConfig, x, h):
+    """The stream after a residual branch ``h``."""
+    if cfg.residual_multiplier != 1.0:
+        # in float32, rounded once: bf16(0.22) would bias every branch
+        h = (h.astype(jnp.float32) * cfg.residual_multiplier).astype(h.dtype)
+    return x + h
+
+
+def _apply_ffn(p: Params, cfg: ModelConfig, x):
+    """The FFN branch and its residual. Returns (x, moe aux loss)."""
     aux = jnp.zeros((), jnp.float32)
     normed = L.rmsnorm(p["norm_mlp"], x, cfg.norm_eps)
     if cfg.is_moe:
@@ -120,38 +143,55 @@ def _apply_attn_layer_full(p: Params, cfg: ModelConfig, x, positions, window):
         h = L.mlp(p["mlp"], normed)
     if cfg.post_norms:
         h = L.rmsnorm(p["post_mlp"], h, cfg.norm_eps)
-    return x + h, aux, kv
+    return _join(cfg, x, h), aux
+
+
+def _apply_attn_layer_full(p: Params, cfg: ModelConfig, x, positions, window):
+    with jax.named_scope("mixer.attention"):
+        h, kv = A.attn_prefill(p["attn"], cfg,
+                               L.rmsnorm(p["norm_attn"], x, cfg.norm_eps),
+                               positions, window=window)
+    if cfg.post_norms:
+        h = L.rmsnorm(p["post_attn"], h, cfg.norm_eps)
+    x, aux = _apply_ffn(p, cfg, _join(cfg, x, h))
+    return x, aux, kv
 
 
 def _apply_mamba_layer_full(p: Params, cfg: ModelConfig, x,
                             initial: Optional[S.SSMState] = None):
-    h, state = S.mamba_prefill(p["mamba"], cfg,
-                               L.rmsnorm(p["norm"], x, cfg.norm_eps), initial)
-    return x + h, state
+    """Returns (x, moe aux loss, final SSM state)."""
+    with jax.named_scope("mixer.mamba"):
+        h, state = S.mamba_prefill(p["mamba"], cfg,
+                                   L.rmsnorm(p["norm"], x, cfg.norm_eps),
+                                   initial)
+    x = _join(cfg, x, h)
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.layer_kinds:
+        x, aux = _apply_ffn(p, cfg, x)
+    return x, aux, state
 
 
 def _apply_attn_layer_decode(p: Params, cfg: ModelConfig, x, lc: Cache,
                              cache_len, window, ring: bool):
-    h, new_lc = A.attn_decode_cached(
-        p["attn"], cfg, L.rmsnorm(p["norm_attn"], x, cfg.norm_eps),
-        lc, cache_len, window=window, ring=ring)
+    with jax.named_scope("mixer.attention"):
+        h, new_lc = A.attn_decode_cached(
+            p["attn"], cfg, L.rmsnorm(p["norm_attn"], x, cfg.norm_eps),
+            lc, cache_len, window=window, ring=ring)
     if cfg.post_norms:
         h = L.rmsnorm(p["post_attn"], h, cfg.norm_eps)
-    x = x + h
-    normed = L.rmsnorm(p["norm_mlp"], x, cfg.norm_eps)
-    if cfg.is_moe:
-        h, _ = M.moe_ffn(p["moe"], cfg, normed)
-    else:
-        h = L.mlp(p["mlp"], normed)
-    if cfg.post_norms:
-        h = L.rmsnorm(p["post_mlp"], h, cfg.norm_eps)
-    return x + h, new_lc
+    x, _ = _apply_ffn(p, cfg, _join(cfg, x, h))
+    return x, new_lc
 
 
 def _apply_mamba_layer_decode(p: Params, cfg: ModelConfig, x, state: S.SSMState):
-    h, new_state = S.mamba_decode(p["mamba"], cfg,
-                                  L.rmsnorm(p["norm"], x, cfg.norm_eps), state)
-    return x + h, new_state
+    with jax.named_scope("mixer.mamba"):
+        h, new_state = S.mamba_decode(p["mamba"], cfg,
+                                      L.rmsnorm(p["norm"], x, cfg.norm_eps),
+                                      state)
+    x = _join(cfg, x, h)
+    if cfg.layer_kinds:
+        x, _ = _apply_ffn(p, cfg, x)
+    return x, new_state
 
 
 # --------------------------------------------------------------------------
@@ -252,6 +292,8 @@ def _embed_inputs(params, cfg: ModelConfig, tokens: Optional[jax.Array],
     x = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     if cfg.scale_embeddings:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     return x
 
 
@@ -280,11 +322,11 @@ def forward(
         for i, kind in enumerate(pattern):
             p = slot_params[f"slot{i}"]
             if kind == "mamba":
-                x, _ = _apply_mamba_layer_full(p, cfg, x)
+                x, a, _ = _apply_mamba_layer_full(p, cfg, x)
             else:
                 x, a, _ = _apply_attn_layer_full(p, cfg, x, positions,
                                                  slot_window(cfg, kind))
-                aux = aux + a
+            aux = aux + a
         if cfg.family == "hybrid":
             x, a, _ = _apply_attn_layer_full(params["shared"], cfg, x,
                                              positions, None)
@@ -302,11 +344,11 @@ def forward(
     for j, kind in enumerate(tail):
         p = params["tail"][j]
         if kind == "mamba":
-            x, _ = _apply_mamba_layer_full(p, cfg, x)
+            x, a, _ = _apply_mamba_layer_full(p, cfg, x)
         else:
             x, a, _ = _apply_attn_layer_full(p, cfg, x, positions,
                                              slot_window(cfg, kind))
-            aux = aux + a
+        aux = aux + a
 
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if return_hidden:
@@ -364,7 +406,7 @@ def prefill(
         for i, kind in enumerate(pattern):
             p = slot_params[f"slot{i}"]
             if kind == "mamba":
-                x, st = _apply_mamba_layer_full(p, cfg, x)
+                x, _, st = _apply_mamba_layer_full(p, cfg, x)
                 new_caches[f"slot{i}"] = st
             else:
                 x, _, (k, v) = _apply_attn_layer_full(p, cfg, x, positions,
@@ -386,7 +428,7 @@ def prefill(
     for j, kind in enumerate(tail):
         p = params["tail"][j]
         if kind == "mamba":
-            x, st = _apply_mamba_layer_full(p, cfg, x)
+            x, _, st = _apply_mamba_layer_full(p, cfg, x)
             tail_caches.append(st)
         else:
             x, _, (k, v) = _apply_attn_layer_full(p, cfg, x, positions,

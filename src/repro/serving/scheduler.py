@@ -151,7 +151,7 @@ class ContinuousBatcher:
         def splice(cache, cache1, tokens, slot, tok):
             self.traces += 1
 
-            def put(batch_leaf, one_leaf):
+            def put(path, batch_leaf, one_leaf):
                 if batch_leaf.ndim == 0:
                     return batch_leaf
                 # find the batch axis (with one slot, the first of size 1)
@@ -161,8 +161,12 @@ class ContinuousBatcher:
                         return jax.lax.dynamic_update_slice_in_dim(
                             batch_leaf, one_leaf.astype(batch_leaf.dtype),
                             slot, axis=ax)
-                return batch_leaf
-            new_cache = jax.tree.map(put, dict(cache), dict(cache1))
+                raise ValueError(
+                    f"cannot splice cache leaf {jax.tree_util.keystr(path)}"
+                    f": no axis of {batch_leaf.shape} holds {num_slots} "
+                    f"slots where the request's {one_leaf.shape} holds 1")
+            new_cache = jax.tree_util.tree_map_with_path(
+                put, dict(cache), dict(cache1))
             new_cache["len"] = cache["len"]  # batch len: see step
             return new_cache, tokens.at[slot, 0].set(tok)
 

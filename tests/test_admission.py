@@ -20,7 +20,9 @@ from repro.models import api  # noqa: E402
 from repro.serving import scheduler as sched  # noqa: E402
 from repro.serving.decode import generate  # noqa: E402
 
-ARCHS = ["mamba2-370m", "llama3.2-1b"]
+#: an SSM, an attention model, and a hybrid whose cache holds KV and SSM
+#: state side by side in one scanned period
+ARCHS = ["mamba2-370m", "llama3.2-1b", "granite-4.0-h-small"]
 SLOTS = 2
 MAX_LEN = 2 * sched.PREFILL_BUCKET + 16
 
@@ -122,3 +124,30 @@ def test_splice_declares_the_batch_cache_donated(arch):
     n_cache = len(jax.tree.leaves(b.cache))
     donors = parse_declared_donors(lowered.as_text())
     assert set(range(n_cache)) <= donors and len(donors) == n_cache + 1
+
+
+def test_splice_raises_on_a_leaf_without_a_batch_axis():
+    """A mixed KV and SSM cache splices every leaf into its slot; a leaf
+    with no axis of the slot count is an error, not a leaf left as it
+    was."""
+    cfg, params = _model("granite-4.0-h-small")
+    b = sched.ContinuousBatcher(params, cfg, num_slots=SLOTS,
+                                max_len=MAX_LEN, eos_id=-1)
+    kinds = {type(leaf).__name__ for leaf in b.cache["slots"].values()}
+    assert kinds == {"dict", "SSMState"}
+    ids = np.arange(sched.PREFILL_BUCKET, dtype=np.int32)[None]
+    tok, cache1 = b._prefill(params, ids, np.int32(sched.PREFILL_BUCKET))
+    want = jax.tree.map(np.asarray, cache1)
+    cache, _ = b._splice(b.cache, cache1, b.tokens, np.int32(1), tok)
+    # slot 1 of every leaf is the request's; slot 0 is still empty
+    for got, one in zip(jax.tree.leaves(cache["slots"]),
+                        jax.tree.leaves(want["slots"])):
+        np.testing.assert_array_equal(np.asarray(got[:, 1:2]), one)
+        assert not np.asarray(got[:, 0]).any()
+
+    # (the splice donated the batch cache and tokens: build fresh ones)
+    bad = dict(api.init_cache(cfg, SLOTS, MAX_LEN), extra=jnp.zeros((3,)))
+    bad1 = dict(cache1, extra=jnp.zeros((3,)))
+    with pytest.raises(ValueError, match="cannot splice cache leaf"):
+        b._splice(bad, bad1, jnp.zeros((SLOTS, 1), jnp.int32), np.int32(0),
+                  tok)

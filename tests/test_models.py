@@ -140,10 +140,11 @@ def test_training_loss_decreases():
 
 
 def test_moe_capacity_factor_lossless_at_e_over_k(rng):
-    """With cf = E/k the dispatch drops nothing: output == dense compute."""
+    """The layer drops no token: output == every token through its top-k
+    experts."""
     from repro.models import moe as M
     cfg = get_config("grok-1-314b", reduced=True).replace(
-        dtype="float32", param_dtype="float32", moe_capacity_factor=2.0)
+        dtype="float32", param_dtype="float32")
     params = M.init_moe(rng, cfg)
     x = jax.random.normal(rng, (2, 16, cfg.d_model)) * 0.3
     out, aux = M.moe_ffn(params, cfg, x)

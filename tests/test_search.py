@@ -130,3 +130,24 @@ def test_initialization_disables_non_frontier_model_variants(cuad_result):
     for v in variants:
         if v not in front:
             assert v.disabled
+
+
+@pytest.mark.parametrize("budget", [12, 40])
+def test_initial_sweep_takes_the_cheapest_models_the_root_admits(budget):
+    """The initialization's model variants widen the root at once: no more
+    of them than the root's cap at the end of the budget plus one round's
+    slack, and those of the cheapest models."""
+    from repro.core.models_catalog import catalog
+    from repro.core.search import DEFAULT_ROUND_WIDTH
+
+    w = WORKLOADS["cuad"]()
+    res = MOARSearch(w, SimBackend(seed=0, domain=w.domain), budget=budget,
+                     seed=0).run()
+    swept = [c.last_action[len("model_sub("):-1] for c in res.root.children
+             if c.last_action.startswith("model_sub(")]
+    cap = widening_cap(budget) + DEFAULT_ROUND_WIDTH - 1
+    assert 0 < len(swept) <= cap
+    cards = catalog()
+    price = lambda m: cards[m].price_in + cards[m].price_out
+    cheapest = sorted(cards, key=price)[:cap]
+    assert set(swept) <= set(cheapest)
