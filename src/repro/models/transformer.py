@@ -464,8 +464,7 @@ def decode_step(
     x = shard_activation(_embed_inputs(params, cfg, token), seq_dim=None)
     cache_len = cache["len"]
 
-    def period_body(x, xs):
-        slot_params, slot_caches = xs
+    def period_body(x, slot_params, slot_caches):
         x = shard_activation(x, seq_dim=None)
         new_caches = {}
         for i, kind in enumerate(pattern):
@@ -486,13 +485,28 @@ def decode_step(
             new_caches["shared"] = nc
         return x, new_caches
 
+    # The stacked caches ride in the carry and each period's slice is
+    # written back in place: as the scan's ys they would be a fresh buffer,
+    # copied whole into the donated cache after the loop.
+    def scan_body(carry, slot_params):
+        x, i, stacked = carry
+        here = jax.tree.map(
+            lambda leaf: jax.lax.dynamic_index_in_dim(leaf, i, keepdims=False),
+            stacked)
+        x, new_caches = period_body(x, slot_params, here)
+        stacked = jax.tree.map(
+            lambda leaf, new: jax.lax.dynamic_update_index_in_dim(
+                leaf, new.astype(leaf.dtype), i, 0),
+            stacked, new_caches)
+        return (x, i + 1, stacked), None
+
     if n_full > 0:
         scan_caches = dict(cache["slots"])
         if cfg.family == "hybrid":
             scan_caches["shared"] = cache["shared"]
-        x, new_stacked = jax.lax.scan(period_body, x,
-                                      (params["layers"], scan_caches),
-                                      length=n_full)
+        (x, _, new_stacked), _ = jax.lax.scan(
+            scan_body, (x, jnp.zeros((), jnp.int32), scan_caches),
+            params["layers"], length=n_full)
         new_slots = {k: v for k, v in new_stacked.items() if k != "shared"}
     else:
         new_slots, new_stacked = {}, {}
