@@ -3,8 +3,7 @@
   PYTHONPATH=src python -m benchmarks.run [--seed 0] [--refresh]
   PYTHONPATH=src python -m benchmarks.run --only table4,fig4
 
-Optimizer results are cached in artifacts/bench/results_seed<k>.json and
-the dry-run artifacts in artifacts/dryrun/ (produced by repro.launch.dryrun).
+Optimizer results are cached in artifacts/bench/results_seed<k>.json.
 """
 
 from __future__ import annotations
@@ -13,9 +12,8 @@ import argparse
 import time
 
 from benchmarks import (ablations, fig4_pareto, insights, kernels_bench,
-                        roofline_table, stability, table4_accuracy,
-                        table5_cost, table6_models, table8_latency,
-                        table9_overhead)
+                        stability, table4_accuracy, table5_cost,
+                        table6_models, table8_latency, table9_overhead)
 from benchmarks.common import load_or_run
 
 SUITES = {
@@ -27,7 +25,6 @@ SUITES = {
     "fig4": fig4_pareto.run,
     "insights": insights.run,
     "kernels": kernels_bench.run,
-    "roofline": roofline_table.run,
     "ablations": ablations.run,
     "stability": stability.run,
 }
@@ -42,7 +39,7 @@ def main() -> None:
 
     picked = [s.strip() for s in args.only.split(",") if s.strip()] or \
         list(SUITES)
-    needs_results = any(s not in ("kernels", "roofline") for s in picked)
+    needs_results = any(s != "kernels" for s in picked)
     results = None
     if needs_results:
         t0 = time.time()

@@ -13,7 +13,7 @@ from repro.analysis.compiled.audit import (audit_kernels,  # noqa: F401
 from repro.analysis.compiled.diagnostics import (  # noqa: F401
     ALL_CODES, DTYPE_UPCAST, HOST_TRANSFER, LOOP_TRANSFER,
     NON_DONATED_BUFFER, PALLAS_BLOCK_SHAPE, PALLAS_VMEM, RECOMPILE_RISK,
-    SEV_ERROR, SEV_WARNING, SHARDING_INCONSISTENCY, CompiledAnalysisError,
+    SEV_ERROR, SEV_WARNING, CompiledAnalysisError,
     CompiledDiagnostic, CompiledReport, merge_reports)
 from repro.analysis.compiled.hlo_lint import (check_donation,  # noqa: F401
                                               check_transfers,
@@ -25,5 +25,3 @@ from repro.analysis.compiled.pallas_lint import (  # noqa: F401
     audit_kernel, check_tpu_tiling, default_kernel_cases)
 from repro.analysis.compiled.recompile import (  # noqa: F401
     check_serving_recompile, prefill_shape_census)
-from repro.analysis.compiled.sharding_lint import (  # noqa: F401
-    check_sharding_consistency, validate_spec_tree)

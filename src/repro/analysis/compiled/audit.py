@@ -4,9 +4,8 @@
 reduced config at the serving shapes ``JaxBackend`` actually uses:
 
 - jaxpr tier (always): dtype-upcast lint over the decode-step and
-  prefill traces, recompile-risk lint over the serving jit sites,
-  sharding-consistency over both production mesh schemes. Tracing +
-  eval_shape only — milliseconds, safe for construction-time gates.
+  prefill traces, recompile-risk lint over the serving jit sites.
+  Tracing + eval_shape only — milliseconds, safe for construction-time gates.
 - HLO tier (``compile=True``): lowers and compiles the decode step with
   the scheduler's donation declaration, then runs the transfer and
   donation lints over the optimized module text. Seconds per model —
@@ -30,7 +29,6 @@ from repro.analysis.compiled.jaxpr_lint import check_dtype_upcast
 from repro.analysis.compiled.pallas_lint import (audit_kernel,
                                                  default_kernel_cases)
 from repro.analysis.compiled.recompile import check_serving_recompile
-from repro.analysis.compiled.sharding_lint import check_sharding_consistency
 
 #: serving shapes mirrored from ``JaxBackend`` (MAX_PROMPT_TOKENS=96,
 #: max_new_tokens=8, +8 slack) at a small slot count
@@ -82,7 +80,6 @@ def audit_model(arch: str, *, compile: bool = True,
     report.extend(check_serving_recompile(
         cfg, subject=arch, max_prompt_tokens=AUDIT_MAX_PROMPT,
         max_len=AUDIT_MAX_LEN))
-    report.extend(check_sharding_consistency(cfg, subject=arch))
 
     # HLO tier ------------------------------------------------------------
     if compile:

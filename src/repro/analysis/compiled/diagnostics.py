@@ -5,9 +5,9 @@ frozen diagnostic records with a stable ``code`` vocabulary, a report
 object with errors/warnings/ok/clean accessors, and dict round-trips for
 the CLI/CI surfaces. The difference is the anchor: pipeline diagnostics
 point at an operator index; compiled diagnostics point at a *site* — a
-jit entry point, an HLO computation, a Pallas kernel, or a sharding
-table — identified by a free-form ``site`` string plus the model/kernel
-``subject`` the audit was running over.
+jit entry point, an HLO computation or a Pallas kernel — identified by a
+free-form ``site`` string plus the model/kernel ``subject`` the audit was
+running over.
 
 Diagnostic codes (severity):
 
@@ -28,11 +28,7 @@ Diagnostic codes (severity):
                                     the padded problem shape / violates
                                     TPU tiling alignment
 ``pallas-vmem``          error      per-step block working set exceeds
-                                    the roofline VMEM budget
-``sharding-inconsistency`` error    a partition spec names an axis the
-                                    mesh doesn't have, reuses an axis
-                                    within one leaf, or shards a dim the
-                                    axis product doesn't divide
+                                    the v5e VMEM budget
 =======================  =========  ====================================
 """
 
@@ -51,12 +47,10 @@ DTYPE_UPCAST = "dtype-upcast"
 NON_DONATED_BUFFER = "non-donated-buffer"
 PALLAS_BLOCK_SHAPE = "pallas-block-shape"
 PALLAS_VMEM = "pallas-vmem"
-SHARDING_INCONSISTENCY = "sharding-inconsistency"
 
 ALL_CODES = (
     RECOMPILE_RISK, HOST_TRANSFER, LOOP_TRANSFER, DTYPE_UPCAST,
     NON_DONATED_BUFFER, PALLAS_BLOCK_SHAPE, PALLAS_VMEM,
-    SHARDING_INCONSISTENCY,
 )
 
 
@@ -71,7 +65,7 @@ class CompiledDiagnostic:
     code: str
     severity: str
     subject: str        # model arch or kernel name the audit ran over
-    site: str           # jit entry / HLO computation / kernel / spec path
+    site: str           # jit entry / HLO computation / kernel
     message: str
     data: Dict[str, Any] = field(default_factory=dict, hash=False)
 

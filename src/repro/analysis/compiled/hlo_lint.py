@@ -1,9 +1,8 @@
 """Compiled-HLO lint: hot-loop transfers and missing buffer donation.
 
-Both checks walk optimized HLO text through the existing
-``launch/hlo_analysis.py`` parser, so trip-weighted "is this inside the
-hot loop" reasoning reuses the same call-graph/multiplier machinery the
-roofline uses.
+Both checks walk optimized HLO text through the ``hlo_analysis`` parser,
+so trip-weighted "is this inside the hot loop" reasoning reuses its
+call-graph/multiplier machinery.
 
 Transfer lint
   ``host-transfer`` (error): infeed/outfeed/send/recv (or host-annotated
@@ -33,7 +32,7 @@ from typing import Dict, List, Set, Tuple
 from repro.analysis.compiled.diagnostics import (
     HOST_TRANSFER, LOOP_TRANSFER, NON_DONATED_BUFFER, SEV_ERROR, SEV_WARNING,
     CompiledDiagnostic, diag)
-from repro.launch.hlo_analysis import (
+from repro.analysis.compiled.hlo_analysis import (
     _SHAPE_RE, _shape_dims, _type_bytes, compute_multipliers,
     parse_computations)
 

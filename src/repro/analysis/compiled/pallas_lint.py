@@ -15,7 +15,7 @@ actually requires:
   and ``dt`` blocks break the rule and it has never compiled for a chip;
 - the per-grid-step VMEM working set — input + output block tiles
   double-buffered (Pallas pipelines the next tile's DMA against compute)
-  plus f32 scratch — must fit the roofline table's per-core VMEM.
+  plus f32 scratch — must fit the per-core VMEM of ``repro.hardware.HW``.
 
 ``default_kernel_cases()`` yields the shapes the repo actually launches:
 the reduced-config model dims crossed with both the kernel-bench block
@@ -29,7 +29,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from repro.analysis.compiled.diagnostics import (
     PALLAS_BLOCK_SHAPE, PALLAS_VMEM, SEV_ERROR, CompiledDiagnostic, diag)
-from repro.launch.roofline import HW
+from repro.hardware import HW
 
 _DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4,
                 "int8": 1, "int32": 4}

@@ -2,15 +2,9 @@
 
 This is the bridge that makes the assigned architectures native to the
 paper: MOAR's model-substitution directive chooses among *these* models,
-and their $/1M-token prices are derived from the roofline analysis of each
-arch's serve/prefill step on the production mesh (chip-seconds per token x
-$/chip-hour), not an API price sheet.
-
-``derive_prices(artifact_dir)`` reads the dry-run JSON artifacts
-(artifacts/dryrun/pod16x16/<arch>__{prefill_32k,decode_32k}.json) and
-prices tokens by the roofline step-time lower bound. When artifacts are
-absent (unit tests), ``analytic_price`` applies the same formulas from
-config-level FLOP/byte counts.
+and ``analytic_price`` derives their $/1M-token prices from each arch's
+config-level FLOP/byte counts against the v5e peaks (chip-seconds per
+token x $/chip-hour), not an API price sheet.
 
 Assumptions (documented in DESIGN.md): $1.20 per chip-hour (v5e on-demand
 ballpark), 40% prefill MFU, decode amortized over the assigned decode
@@ -19,13 +13,11 @@ batch, 1.3x HBM overhead for serving state.
 
 from __future__ import annotations
 
-import json
-import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
 from repro.configs import ARCHS
-from repro.launch.roofline import HW
+from repro.hardware import HW
 
 CHIP_HOUR_USD = 1.20
 PREFILL_MFU = 0.40
@@ -120,44 +112,17 @@ def analytic_price(arch: str) -> Dict[str, float]:
             "out": chip_s_per_mtok_out * rate}
 
 
-def derive_prices(artifact_dir: str) -> Dict[str, Dict[str, float]]:
-    """Prices from dry-run roofline artifacts: step-time lower bound x
-    chips x $rate / tokens per step."""
-    out: Dict[str, Dict[str, float]] = {}
-    rate = CHIP_HOUR_USD / 3600.0
-    for arch in ARCHS:
-        prices = analytic_price(arch)  # fallback fill
-        for kind, key in (("prefill_32k", "in"), ("decode_32k", "out")):
-            path = os.path.join(artifact_dir, "pod16x16",
-                                f"{arch}__{kind}.json")
-            if not os.path.exists(path):
-                continue
-            with open(path) as f:
-                rep = json.load(f)
-            if rep.get("status") != "ok":
-                continue
-            tokens = max(rep.get("tokens_per_step", 1), 1)
-            step_s = rep.get("step_time_lower_bound_s", 0.0)
-            chips = rep.get("n_devices", 256)
-            prices[key] = step_s * chips * rate * 1e6 / tokens
-        out[arch] = prices
-    return out
-
-
 _CATALOG: Optional[Dict[str, ModelCard]] = None
 
 
-def catalog(artifact_dir: Optional[str] = None,
-            refresh: bool = False) -> Dict[str, ModelCard]:
+def catalog(refresh: bool = False) -> Dict[str, ModelCard]:
     global _CATALOG
     if _CATALOG is not None and not refresh:
         return _CATALOG
-    prices = derive_prices(artifact_dir) if artifact_dir else \
-        {a: analytic_price(a) for a in ARCHS}
     cards = {}
     for arch, share in ARCHS.items():
         cfg = share.deployment()
-        p = prices.get(arch) or analytic_price(arch)
+        p = analytic_price(arch)
         cards[arch] = ModelCard(
             name=arch,
             family=cfg.family,

@@ -22,7 +22,7 @@ gates what downstream operators can find):
 - per-(model, domain) specialization jitter makes the best model
   workload-dependent (paper Table 6);
 - costs follow the paper's cost model: tokens x per-token price of the
-  model, prices derived from the roofline analysis (models_catalog).
+  model, prices derived from each arch's FLOP/byte counts (models_catalog).
 
 Determinism: every stochastic decision hashes (seed, doc id, op fields,
 model, tag) — identical pipelines on identical data give identical

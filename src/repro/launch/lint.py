@@ -12,9 +12,9 @@ provably-wrong ones.
 
 Compile mode (``--compile``) runs the compile-path static analyzer
 (``repro.analysis.compiled``) over the model zoo and the Pallas kernel
-cases: dtype-upcast / recompile-risk / sharding lint from traced jaxprs,
-transfer + donation lint from the compiled decode-step HLO, and
-block-shape + VMEM lint from the roofline hardware table.
+cases: dtype-upcast / recompile-risk lint from traced jaxprs, transfer +
+donation lint from the compiled decode-step HLO, and block-shape + VMEM
+lint against the v5e table in ``repro.hardware``.
 
 Usage:
   python -m repro.launch.lint                      # human report

@@ -134,13 +134,6 @@ def project_qkv(params, cfg: ModelConfig, x: jax.Array, kv_x: Optional[jax.Array
     return q, k, v
 
 
-# §Perf knob: when True, local (sliding-window) layers slice K/V to the
-# [chunk_start - window, chunk_end) band per query chunk instead of scoring
-# the full sequence and masking — exact, and cuts local-layer attention
-# FLOPs/bytes by ~S/(window+chunk). Baselined OFF; see EXPERIMENTS.md §Perf.
-WINDOWED_CHUNK_ATTENTION = False
-
-
 def attend_chunked(
     q: jax.Array,  # (B,S,H,Hd)
     k: jax.Array,  # (B,Sk,Kh,Hd)
@@ -155,8 +148,8 @@ def attend_chunked(
     scale: Optional[float] = None,
 ) -> jax.Array:
     """Query-chunked attention: bounds the live (B,H,chunk,Sk) score tensor
-    instead of materializing (B,H,S,Sk). The chunk body is rematerialized
-    (jax.checkpoint) so the backward pass also never holds more than one
+    instead of materializing (B,H,S,Sk). The backward pass recomputes the
+    chunk body (jax.checkpoint), so it also never holds more than one
     chunk of probabilities — the XLA-level analogue of flash attention,
     used whenever the Pallas kernel is not routed.
     """
@@ -166,35 +159,17 @@ def attend_chunked(
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         q_pos = jnp.pad(q_pos, ((0, 0), (0, pad)), constant_values=-1)
     nq = q.shape[1] // chunk
-    s_k = k.shape[1]
-
-    windowed = (WINDOWED_CHUNK_ATTENTION and isinstance(window, int)
-                and 0 < window and causal
-                and window + chunk < s_k)
-    band = min(s_k, ((window + chunk + chunk - 1) // chunk) * chunk) \
-        if windowed else s_k
 
     @jax.checkpoint
     def body(carry, xs):
-        qc, qpc, idx = xs  # (B,chunk,H,Hd), (B,chunk), scalar chunk index
-        if windowed:
-            # slice the K/V band covering [chunk_start - window, chunk_end)
-            start = jnp.clip(idx * chunk + chunk - band, 0, s_k - band)
-            kc = jax.lax.dynamic_slice_in_dim(k, start, band, axis=1)
-            vc = jax.lax.dynamic_slice_in_dim(v, start, band, axis=1)
-            kpc = jax.lax.dynamic_slice_in_dim(
-                jnp.broadcast_to(k_pos, (k.shape[0], s_k)), start, band,
-                axis=1)
-        else:
-            kc, vc, kpc = k, v, k_pos
-        out = attend(qc, kc, vc, q_pos=qpc, k_pos=kpc, causal=causal,
+        qc, qpc = xs  # (B,chunk,H,Hd), (B,chunk)
+        out = attend(qc, k, v, q_pos=qpc, k_pos=k_pos, causal=causal,
                      window=window, attn_softcap=attn_softcap, scale=scale)
         return carry, out
 
     qs = q.reshape(b, nq, chunk, h, hd).transpose(1, 0, 2, 3, 4)
     ps = q_pos.reshape(b, nq, chunk).transpose(1, 0, 2)
-    idxs = jnp.arange(nq, dtype=jnp.int32)
-    _, outs = jax.lax.scan(body, None, (qs, ps, idxs))
+    _, outs = jax.lax.scan(body, None, (qs, ps))
     out = outs.transpose(1, 0, 2, 3, 4).reshape(b, nq * chunk, h, hd)
     return out[:, :s]
 
@@ -261,50 +236,6 @@ def attn_prefill(
     return jnp.einsum("...e,ed->...d", out, params["wo"]), (k, v)
 
 
-# §Perf knob (decode): compute attention grouped by kv-head instead of
-# jnp.repeat-expanding K/V to all query heads, and pin the score tensor to
-# the cache's sequence sharding so GSPMD runs a distributed softmax instead
-# of all-gathering the KV cache. Exact; baselined OFF. See EXPERIMENTS §Perf.
-GROUPED_DECODE_ATTENTION = False
-
-
-def attend_grouped_decode(
-    q: jax.Array,        # (B, 1, H, Hd)
-    k: jax.Array,        # (B, S, K, Hd)
-    v: jax.Array,
-    *,
-    q_pos: jax.Array,    # (B, 1)
-    k_pos: jax.Array,    # (1or B, S)
-    window,
-    attn_softcap: float,
-    kv_valid: Optional[jax.Array],  # (B, S)
-    scale: float,
-) -> jax.Array:
-    from repro.models.partitioning import shard_activation
-    b, _, h, hd = q.shape
-    kh = k.shape[2]
-    g = h // kh
-    qg = q.reshape(b, kh, g, hd)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    scores = L.softcap(scores, attn_softcap)
-    delta = q_pos[:, 0][:, None] - k_pos  # (B, S)
-    ok = delta >= 0
-    if window is not None:
-        w = jnp.asarray(window, delta.dtype)
-        ok = ok & jnp.where(w > 0, delta < w, True)
-    if kv_valid is not None:
-        ok = ok & kv_valid
-    scores = jnp.where(ok[:, None, None, :], scores, NEG_INF)
-    # batch pin only: with no head-repeat in the einsum, GSPMD propagates
-    # the cache's own sharding (seq- or head-) into the scores and runs a
-    # distributed softmax instead of gathering the cache
-    scores = shard_activation(scores, seq_dim=None)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, 1, h, hd).astype(q.dtype)
-
-
 def attn_decode(
     params,
     cfg: ModelConfig,
@@ -356,99 +287,18 @@ def attn_decode(
         out = out.reshape(b, 1, -1)
         return (jnp.einsum("...e,ed->...d", out, params["wo"]),
                 (cache_k, cache_v))
-    use_grouped = (GROUPED_DECODE_ATTENTION
-                   and cfg.num_heads != cfg.num_kv_heads  # MHA: repeat is free
-                   and b > 1)  # batch-1 long-context: baseline path is fine
-    if use_grouped:
-        out = attend_grouped_decode(
-            q, cache_k, cache_v,
-            q_pos=pos,
-            k_pos=k_pos[None].astype(jnp.int32),
-            window=window,
-            attn_softcap=cfg.attn_softcap,
-            kv_valid=jnp.broadcast_to(valid[None], (b, smax)),
-            scale=scale,
-        )
-    else:
-        out = attend(
-            q, cache_k, cache_v,
-            q_pos=pos,
-            k_pos=k_pos[None].astype(jnp.int32),
-            causal=True,
-            window=window,
-            attn_softcap=cfg.attn_softcap,
-            kv_valid=jnp.broadcast_to(valid[None], (b, smax)),
-            scale=scale,
-        )
+    out = attend(
+        q, cache_k, cache_v,
+        q_pos=pos,
+        k_pos=k_pos[None].astype(jnp.int32),
+        causal=True,
+        window=window,
+        attn_softcap=cfg.attn_softcap,
+        kv_valid=jnp.broadcast_to(valid[None], (b, smax)),
+        scale=scale,
+    )
     out = out.reshape(b, 1, -1)
     return jnp.einsum("...e,ed->...d", out, params["wo"]), (cache_k, cache_v)
-
-
-def attn_decode_cached(
-    params,
-    cfg: ModelConfig,
-    x: jax.Array,       # (B,1,D)
-    lc,                 # layer cache dict: k/v (+ k_scale/v_scale for int8)
-    cache_len: jax.Array,
-    *,
-    window=None,
-    ring: bool = False,
-):
-    """Dict-based decode entry point; handles int8-quantized KV caches
-    (per-(token,head) absmax scales). The dequantize fuses into the
-    attention dot on TPU; cache capacity halves either way."""
-    if "k_scale" not in lc:
-        out, (ck, cv) = attn_decode(params, cfg, x, lc["k"], lc["v"],
-                                    cache_len, window=window, ring=ring)
-        return out, {"k": ck, "v": cv}
-
-    from repro.serving.kv_cache import dequantize_kv, quantize_kv
-    b = x.shape[0]
-    smax = lc["k"].shape[1]
-    pos = jnp.full((b, 1), cache_len, dtype=jnp.int32)
-    q, k_new, v_new = project_qkv(params, cfg, x)
-    if cfg.position_embedding == "rope":
-        q = L.apply_rope(q, pos, cfg.rope_theta)
-        k_new = L.apply_rope(k_new, pos, cfg.rope_theta)
-    scale = cfg.resolved_attn_scale
-
-    slot = jnp.where(ring, cache_len % smax, jnp.minimum(cache_len, smax - 1))
-    qk, sk = quantize_kv(k_new)
-    qv, sv = quantize_kv(v_new)
-    ck = jax.lax.dynamic_update_slice_in_dim(lc["k"], qk, slot, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(lc["v"], qv, slot, axis=1)
-    csk = jax.lax.dynamic_update_slice_in_dim(lc["k_scale"], sk, slot, axis=1)
-    csv = jax.lax.dynamic_update_slice_in_dim(lc["v_scale"], sv, slot, axis=1)
-    dt = L.dtype_of(cfg.dtype)
-    k_full = dequantize_kv(ck, csk, dt)
-    v_full = dequantize_kv(cv, csv, dt)
-
-    idx = jnp.arange(smax, dtype=jnp.int32)
-    if ring:
-        wraps = (cache_len // smax) * smax
-        k_pos = jnp.where(idx <= (cache_len % smax), wraps + idx,
-                          wraps - smax + idx)
-        valid = k_pos >= 0
-    else:
-        k_pos = idx
-        valid = idx <= cache_len
-    use_grouped = (GROUPED_DECODE_ATTENTION
-                   and cfg.num_heads != cfg.num_kv_heads and b > 1)
-    if use_grouped:
-        out = attend_grouped_decode(
-            q, k_full, v_full, q_pos=pos,
-            k_pos=k_pos[None].astype(jnp.int32), window=window,
-            attn_softcap=cfg.attn_softcap,
-            kv_valid=jnp.broadcast_to(valid[None], (b, smax)), scale=scale)
-    else:
-        out = attend(
-            q, k_full, v_full, q_pos=pos,
-            k_pos=k_pos[None].astype(jnp.int32), causal=True,
-            window=window, attn_softcap=cfg.attn_softcap,
-            kv_valid=jnp.broadcast_to(valid[None], (b, smax)), scale=scale)
-    out = out.reshape(b, 1, -1)
-    return (jnp.einsum("...e,ed->...d", out, params["wo"]),
-            {"k": ck, "v": cv, "k_scale": csk, "v_scale": csv})
 
 
 def attn_cross_decode(

@@ -95,8 +95,6 @@ class ModelConfig:
     tie_embeddings: bool = True
     dtype: str = "bfloat16"        # activation/compute dtype
     param_dtype: str = "bfloat16"  # parameter dtype
-    kv_cache_dtype: str = ""       # "" = dtype; "int8" = quantized KV cache
-    remat: str = "none"            # "none" | "full" — activation checkpointing
     use_pallas: bool = False       # route hot ops through Pallas kernels
     max_seq_len: int = 1 << 19
 

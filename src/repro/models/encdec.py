@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.models import attention as A
 from repro.models import layers as L
-from repro.models.partitioning import shard_activation
 
 Params = Dict[str, Any]
 Cache = Dict[str, Any]
@@ -74,12 +73,11 @@ def init_params(key, cfg: ModelConfig) -> Params:
 
 def encode(params: Params, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
     """frames: (B, T_enc, d_model) stub embeddings -> encoder states."""
-    x = shard_activation(frames.astype(L.dtype_of(cfg.dtype)))
+    x = frames.astype(L.dtype_of(cfg.dtype))
     b, t, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
 
     def body(x, p):
-        x = shard_activation(x)
         h, _ = A.attn_prefill(p["attn"], cfg,
                               L.rmsnorm(p["norm_attn"], x, cfg.norm_eps),
                               positions, causal=False)
@@ -87,8 +85,6 @@ def encode(params: Params, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
         x = x + L.gelu_mlp(p["mlp"], L.rmsnorm(p["norm_mlp"], x, cfg.norm_eps))
         return x, None
 
-    if cfg.remat == "full":
-        body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["enc_layers"], length=cfg.encoder_layers)
     return L.rmsnorm(params["enc_norm"], x, cfg.norm_eps)
 
@@ -108,12 +104,11 @@ def forward(
 ):
     """Teacher-forced decode over the full target sequence."""
     enc_out = encode(params, cfg, frames)
-    x = shard_activation(L.embed(params["embed"], cfg, tokens))
+    x = L.embed(params["embed"], cfg, tokens)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
     def body(x, p):
-        x = shard_activation(x)
         h, _ = A.attn_prefill(p["attn_self"], cfg,
                               L.rmsnorm(p["norm_self"], x, cfg.norm_eps),
                               positions, causal=True)
@@ -125,8 +120,6 @@ def forward(
         x = x + L.gelu_mlp(p["mlp"], L.rmsnorm(p["norm_mlp"], x, cfg.norm_eps))
         return x, None
 
-    if cfg.remat == "full":
-        body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["dec_layers"], length=cfg.num_layers)
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
@@ -148,12 +141,11 @@ def prefill(
     max_len: int,
 ):
     enc_out = encode(params, cfg, frames)
-    x = shard_activation(L.embed(params["embed"], cfg, tokens))
+    x = L.embed(params["embed"], cfg, tokens)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
     def body(x, p):
-        x = shard_activation(x)
         h, (k, v) = A.attn_prefill(p["attn_self"], cfg,
                                    L.rmsnorm(p["norm_self"], x, cfg.norm_eps),
                                    positions, causal=True)
@@ -204,7 +196,6 @@ def decode_step(
 
     def body(x, xs):
         p, sc, cc = xs
-        x = shard_activation(x, seq_dim=None)
         h, (ck, cv) = A.attn_decode(
             p["attn_self"], cfg, L.rmsnorm(p["norm_self"], x, cfg.norm_eps),
             sc["k"], sc["v"], cache_len)

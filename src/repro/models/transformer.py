@@ -41,7 +41,6 @@ from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import ssm as S
-from repro.models.partitioning import shard_activation
 
 Params = Dict[str, Any]
 Cache = Dict[str, Any]
@@ -174,13 +173,13 @@ def _apply_mamba_layer_full(p: Params, cfg: ModelConfig, x,
 def _apply_attn_layer_decode(p: Params, cfg: ModelConfig, x, lc: Cache,
                              cache_len, window, ring: bool):
     with jax.named_scope("mixer.attention"):
-        h, new_lc = A.attn_decode_cached(
+        h, (ck, cv) = A.attn_decode(
             p["attn"], cfg, L.rmsnorm(p["norm_attn"], x, cfg.norm_eps),
-            lc, cache_len, window=window, ring=ring)
+            lc["k"], lc["v"], cache_len, window=window, ring=ring)
     if cfg.post_norms:
         h = L.rmsnorm(p["post_attn"], h, cfg.norm_eps)
     x, _ = _apply_ffn(p, cfg, _join(cfg, x, h))
-    return x, new_lc
+    return x, {"k": ck, "v": cv}
 
 
 def _apply_mamba_layer_decode(p: Params, cfg: ModelConfig, x, state: S.SSMState):
@@ -206,13 +205,6 @@ def _empty_layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int):
         return S.init_ssm_state(cfg, batch)
     size = cfg.local_window if kind == "attn_local" else max_len
     size = min(size, max_len)
-    if cfg.kv_cache_dtype == "int8":
-        return {"k": jnp.zeros((batch, size, cfg.num_kv_heads, hd), jnp.int8),
-                "v": jnp.zeros((batch, size, cfg.num_kv_heads, hd), jnp.int8),
-                "k_scale": jnp.zeros((batch, size, cfg.num_kv_heads),
-                                     jnp.float32),
-                "v_scale": jnp.zeros((batch, size, cfg.num_kv_heads),
-                                     jnp.float32)}
     return {"k": jnp.zeros((batch, size, cfg.num_kv_heads, hd), dt),
             "v": jnp.zeros((batch, size, cfg.num_kv_heads, hd), dt)}
 
@@ -312,13 +304,12 @@ def forward(
 ):
     """Full-sequence forward. Returns (logits_or_hidden, moe_aux_loss)."""
     pattern, n_full, tail = layout(cfg)
-    x = shard_activation(_embed_inputs(params, cfg, tokens, patch_embeds))
+    x = _embed_inputs(params, cfg, tokens, patch_embeds)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
     def period_body(carry, slot_params):
         x, aux = carry
-        x = shard_activation(x)
         for i, kind in enumerate(pattern):
             p = slot_params[f"slot{i}"]
             if kind == "mamba":
@@ -333,13 +324,9 @@ def forward(
             aux = aux + a
         return (x, aux), None
 
-    body = period_body
-    if cfg.remat == "full":
-        body = jax.checkpoint(period_body)
-
     aux = jnp.zeros((), jnp.float32)
     if n_full > 0:
-        (x, aux), _ = jax.lax.scan(body, (x, aux), params["layers"],
+        (x, aux), _ = jax.lax.scan(period_body, (x, aux), params["layers"],
                                    length=n_full)
     for j, kind in enumerate(tail):
         p = params["tail"][j]
@@ -377,11 +364,6 @@ def _seed_attn_cache(cfg: ModelConfig, kind: str, k, v, max_len: int):
         pad = max_len - s
         ck = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         cv = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    if cfg.kv_cache_dtype == "int8":
-        from repro.serving.kv_cache import quantize_kv
-        qk, sk = quantize_kv(ck)
-        qv, sv = quantize_kv(cv)
-        return {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv}
     return {"k": ck, "v": cv}
 
 
@@ -396,12 +378,11 @@ def prefill(
     """Run the prompt through the model, returning (last-position logits,
     populated decode cache)."""
     pattern, n_full, tail = layout(cfg)
-    x = shard_activation(_embed_inputs(params, cfg, tokens, patch_embeds))
+    x = _embed_inputs(params, cfg, tokens, patch_embeds)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
     def period_body(x, slot_params):
-        x = shard_activation(x)
         new_caches = {}
         for i, kind in enumerate(pattern):
             p = slot_params[f"slot{i}"]
@@ -461,11 +442,10 @@ def decode_step(
 ):
     """One autoregressive step. Returns (logits (B,1,V), updated cache)."""
     pattern, n_full, tail = layout(cfg)
-    x = shard_activation(_embed_inputs(params, cfg, token), seq_dim=None)
+    x = _embed_inputs(params, cfg, token)
     cache_len = cache["len"]
 
     def period_body(x, slot_params, slot_caches):
-        x = shard_activation(x, seq_dim=None)
         new_caches = {}
         for i, kind in enumerate(pattern):
             p = slot_params[f"slot{i}"]

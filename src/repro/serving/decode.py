@@ -1,8 +1,8 @@
 """Serving step functions and host-side generation loops.
 
-``make_serve_step`` produces the function the dry-run lowers for decode
-shapes: one token in, (sampled token, updated cache) out. Sampling is
-greedy by default; temperature sampling threads a PRNG key.
+``make_serve_step`` produces the decode step that the batcher and
+``generate`` jit: one token in, (sampled token, updated cache) out.
+Sampling is greedy by default; temperature sampling threads a PRNG key.
 """
 
 from __future__ import annotations

@@ -49,21 +49,3 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int):
 def measured_cache_bytes(cache) -> int:
     return int(sum(x.size * x.dtype.itemsize
                    for x in jax.tree_util.tree_leaves(cache)))
-
-
-# -- int8 KV quantization (per-(token, head) absmax scales) -------------------
-
-
-def quantize_kv(x):
-    """(..., Hd) bf16/f32 -> (int8 values, f32 scales (...,))."""
-    import jax.numpy as jnp
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
-    scale = jnp.maximum(amax / 127.0, 1e-8)
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]),
-                 -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-def dequantize_kv(q, scale, dtype):
-    import jax.numpy as jnp
-    return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)

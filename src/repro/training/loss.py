@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.partitioning import shard_activation
 
 
 def chunked_softmax_xent(
@@ -50,7 +49,6 @@ def chunked_softmax_xent(
         # instead of saving (B, chunk, V) fp32 per chunk across the scan
         nll_sum, count = carry
         h, y, m = xs  # (B, chunk, D), (B, chunk), (B, chunk)
-        h = shard_activation(h)
         logits = jnp.einsum("bsd,vd->bsv", h.astype(jnp.float32),
                             table.astype(jnp.float32))
         if cfg.final_softcap > 0.0:

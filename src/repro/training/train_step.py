@@ -1,8 +1,7 @@
 """Train step factory: microbatched gradient accumulation + AdamW.
 
 Distributed-optimization features (per DESIGN.md):
-- microbatch accumulation via ``lax.scan`` bounds activation memory; with
-  ``cfg.remat='full'`` each scan period recomputes activations backward;
+- microbatch accumulation via ``lax.scan`` bounds activation memory;
 - optional gradient compression: accumulated grads are cast to bf16 before
   the (pjit-induced) data-axis all-reduce, halving collective bytes;
 - parameters/optimizer state are donated at the jit boundary by the

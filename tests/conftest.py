@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here on purpose — smoke tests and
-benches must see the host's single device; only the dry-run forces 512."""
+benches must see the host's single device."""
 
 import jax
 import pytest

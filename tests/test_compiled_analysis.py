@@ -10,15 +10,13 @@ import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.analysis.compiled import (  # noqa: E402
     DTYPE_UPCAST, HOST_TRANSFER, LOOP_TRANSFER, NON_DONATED_BUFFER,
-    PALLAS_BLOCK_SHAPE, PALLAS_VMEM, RECOMPILE_RISK, SHARDING_INCONSISTENCY,
-    CompiledAnalysisError, CompiledReport, audit_kernel, audit_kernels,
-    audit_model, check_donation, check_dtype_upcast, check_serving_recompile,
-    check_tpu_tiling, check_transfers, merge_reports, parse_declared_donors,
-    parse_io_aliases, validate_spec_tree)
+    PALLAS_BLOCK_SHAPE, PALLAS_VMEM, RECOMPILE_RISK, CompiledAnalysisError,
+    CompiledReport, audit_kernel, audit_kernels, audit_model, check_donation,
+    check_dtype_upcast, check_serving_recompile, check_tpu_tiling,
+    check_transfers, merge_reports, parse_declared_donors, parse_io_aliases)
 from repro.configs import get_config  # noqa: E402
 
 # -- transfer lint (synthetic HLO) -----------------------------------------
@@ -259,53 +257,6 @@ def test_recompile_risk_fires_on_uncached_jit_closure(monkeypatch):
     diags = check_serving_recompile(cfg, subject="t")
     assert [d.code for d in diags] == [RECOMPILE_RISK]
     assert diags[0].site == "decode.serve_step"
-
-
-# -- sharding-consistency lint ----------------------------------------------
-
-_SIZES = {"data": 16, "model": 16}
-
-
-def _leaf(*shape):
-    return jax.ShapeDtypeStruct(shape, jnp.float32)
-
-
-def test_sharding_unknown_axis():
-    diags = validate_spec_tree({"w": _leaf(64, 128)}, {"w": P("bogus", None)},
-                               _SIZES, subject="t", site="s")
-    assert [d.code for d in diags] == [SHARDING_INCONSISTENCY]
-    assert "bogus" in diags[0].message
-
-
-def test_sharding_axis_reused_within_leaf():
-    diags = validate_spec_tree({"w": _leaf(64, 128)},
-                               {"w": P("data", "data")},
-                               _SIZES, subject="t", site="s")
-    assert [d.code for d in diags] == [SHARDING_INCONSISTENCY]
-    assert "more than one" in diags[0].message
-
-
-def test_sharding_non_divisible_dim():
-    diags = validate_spec_tree({"w": _leaf(100, 128)}, {"w": P("model", None)},
-                               _SIZES, subject="t", site="s")
-    assert [d.code for d in diags] == [SHARDING_INCONSISTENCY]
-    assert "not divisible" in diags[0].message
-
-
-def test_sharding_leaf_count_mismatch():
-    diags = validate_spec_tree({"a": _leaf(8), "b": _leaf(8)},
-                               {"a": P(None)}, _SIZES,
-                               subject="t", site="s")
-    assert [d.code for d in diags] == [SHARDING_INCONSISTENCY]
-    assert "diverged" in diags[0].message
-
-
-def test_sharding_valid_tree_clean():
-    diags = validate_spec_tree(
-        {"w": _leaf(64, 128), "b": _leaf(64)},
-        {"w": P("data", "model"), "b": P(None)},
-        _SIZES, subject="t", site="s")
-    assert diags == []
 
 
 # -- report plumbing --------------------------------------------------------

@@ -16,7 +16,6 @@ from repro.configs import get_config
 from repro.models import api
 from repro.models import layers as L
 from repro.models import transformer as T
-from repro.models.partitioning import shard_activation
 
 ARCHS = ["mamba2-370m", "granite-4.0-h-small", "zamba2-2.7b", "gemma2-9b",
          "llama3.2-1b", "granite-moe-1b-a400m"]
@@ -26,12 +25,11 @@ B, PROMPT, MAX_LEN, STEPS = 2, 6, 16, 4
 def _decode_step_xs_ys(params, cfg, token, cache):
     """The period scan with the caches as ``xs`` and the new ones as ``ys``."""
     pattern, n_full, tail = T.layout(cfg)
-    x = shard_activation(T._embed_inputs(params, cfg, token), seq_dim=None)
+    x = T._embed_inputs(params, cfg, token)
     cache_len = cache["len"]
 
     def period_body(x, xs):
         slot_params, slot_caches = xs
-        x = shard_activation(x, seq_dim=None)
         new_caches = {}
         for i, kind in enumerate(pattern):
             p = slot_params[f"slot{i}"]

@@ -71,12 +71,10 @@ def test_no_token_dropped_at_the_largest_prefill_bucket(rng):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_attention_paths_agree(rng, monkeypatch):
-    """NoPE and the configured scale on every path: the grouped decode
-    attention and the Pallas kernels (interpreted here) give the plain
-    path's logits."""
-    from repro.models import attention as A
-
+def test_attention_paths_agree(rng):
+    """NoPE and the configured scale on every path: the jnp decode
+    attention and the Pallas kernels (interpreted here) give the full
+    forward's logits."""
     cfg = _cfg32()
     params = api.init_params(rng, cfg)
     toks = jax.random.randint(rng, (2, 20), 0, cfg.vocab_size)
@@ -85,7 +83,6 @@ def test_attention_paths_agree(rng, monkeypatch):
                             tokens=toks)
     np.testing.assert_allclose(np.asarray(pallas), np.asarray(full),
                                atol=2e-6)
-    monkeypatch.setattr(A, "GROUPED_DECODE_ATTENTION", True)
     for c in (cfg, cfg.replace(use_pallas=True)):
         _, cache = api.prefill(params, c, 48, tokens=toks[:, :19])
         dl, _ = api.decode_step(params, c, toks[:, 19:], cache)

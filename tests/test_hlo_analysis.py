@@ -9,10 +9,9 @@ the dtype byte table (sub-byte s4/u4, f8 variants), and the
 
 import pytest
 
-from repro.launch.hlo_analysis import (HLOParseError, _trip_count,
-                                       _type_bytes, analyze,
-                                       compute_multipliers,
-                                       parse_computations)
+from repro.analysis.compiled.hlo_analysis import (
+    HLOParseError, _trip_count, _type_bytes, analyze, compute_multipliers,
+    parse_computations)
 
 _NESTED_WHILE_HLO = """
 HloModule test

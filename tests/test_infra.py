@@ -1,5 +1,5 @@
-"""Data pipeline, checkpointing, serving scheduler, sharding rules, HLO
-analysis, cost catalog."""
+"""Data pipeline, checkpointing, serving scheduler, HLO analysis, cost
+catalog."""
 
 import os
 import tempfile
@@ -179,67 +179,11 @@ def test_cache_bytes_matches_measured():
         assert abs(est - got) / got < 0.25, (arch, est, got)
 
 
-# -- sharding rules -----------------------------------------------------------------
-
-
-def test_fit_axes_divisibility():
-    from repro.launch.sharding import _fit_axes
-    sizes = {"pod": 2, "data": 16, "model": 16}
-    assert _fit_axes(256, ("data",), sizes) == ("data",)
-    assert _fit_axes(8, ("model",), sizes) is None
-    assert _fit_axes(32, ("pod", "data"), sizes) == ("pod", "data")
-    assert _fit_axes(2, ("pod", "data"), sizes) == ("pod",)
-
-
-def test_param_specs_always_divisible():
-    """Every sharded dim must divide evenly on the production mesh."""
-    from repro.launch import sharding as shd
-    from repro.models import api
-    sizes = {"data": 16, "model": 16}
-    pol = shd.ShardingPolicy(data_axes=("data",), model_axes=("model",),
-                             axis_sizes=sizes)
-    for arch, cfg in ARCHS.items():
-        params = jax.eval_shape(
-            lambda cfg=cfg: api.init_params(jax.random.PRNGKey(0), cfg))
-        specs = shd.param_pspecs(cfg, params, pol)
-
-        def check(path, leaf, spec, arch=arch):
-            for dim, entry in zip(leaf.shape, tuple(spec)):
-                if entry is None:
-                    continue
-                axes = entry if isinstance(entry, tuple) else (entry,)
-                total = 1
-                for a in axes:
-                    total *= sizes[a]
-                assert dim % total == 0, (arch, path, leaf.shape, spec)
-
-        jax.tree_util.tree_map_with_path(
-            lambda p, leaf, s: check(p, leaf, s), params, specs)
-
-
-def test_opt_specs_follow_params():
-    from repro.launch import sharding as shd
-    from repro.models import api
-    from repro.training.adafactor import init_opt_state as init_af
-    from repro.training.adamw import init_opt_state as init_adamw
-    cfg = ARCHS["llama3.2-1b"]
-    pol = shd.ShardingPolicy(data_axes=("data",), model_axes=("model",),
-                             axis_sizes={"data": 16, "model": 16})
-    params = jax.eval_shape(lambda: api.init_params(jax.random.PRNGKey(0), cfg))
-    pspecs = shd.param_pspecs(cfg, params, pol)
-    adamw = jax.eval_shape(init_adamw, params)
-    ospecs = shd.opt_pspecs(cfg, adamw, pspecs)
-    assert ospecs.m is pspecs and ospecs.v is pspecs
-    af = jax.eval_shape(init_af, params)
-    fspecs = shd.opt_pspecs(cfg, af, pspecs)
-    assert fspecs.m is pspecs
-
-
 # -- HLO analysis ----------------------------------------------------------------
 
 
 def test_hlo_trip_count_weighting():
-    from repro.launch.hlo_analysis import analyze
+    from repro.analysis.compiled.hlo_analysis import analyze
 
     def f(w, x):
         def outer(x, _):
@@ -259,7 +203,7 @@ def test_hlo_trip_count_weighting():
 
 
 def test_hlo_collective_parsing_synthetic():
-    from repro.launch.hlo_analysis import analyze
+    from repro.analysis.compiled.hlo_analysis import analyze
     hlo = """
 HloModule test
 
@@ -305,17 +249,3 @@ def test_catalog_prices_scale_with_size():
     assert big["in"] > small["in"] * 10
     for c in cards.values():
         assert c.price_in > 0 and c.price_out > 0
-
-
-def test_roofline_report_terms():
-    from repro.launch.roofline import HW, RooflineReport
-    rep = RooflineReport(
-        arch="x", shape="train_4k", mesh="pod16x16", n_devices=256,
-        kind="train", tokens_per_step=1000,
-        flops=HW["peak_flops"], bytes_accessed=HW["hbm_bw"],
-        collective_bytes=0.0, collective_breakdown={},
-        model_flops_global=HW["peak_flops"] * 128).finalize()
-    assert abs(rep.compute_s - 1.0) < 1e-9
-    assert abs(rep.memory_s - 1.0) < 1e-9
-    assert rep.bottleneck in ("compute", "memory")
-    assert 0 < rep.useful_ratio <= 1.0
